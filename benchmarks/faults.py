@@ -41,11 +41,8 @@ from typing import Dict, List, Optional
 
 from benchmarks.common import percentile, row
 
-try:
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
+import jax
+import jax.numpy as jnp
 
 SERVICE_S = 0.01          # per-row service time of the CPU bottleneck
 N_CPU = 2                 # capacity = N_CPU / SERVICE_S = 200 rows/s
@@ -189,8 +186,6 @@ def _series_count(rt, key: str) -> int:
 def run(duration_s: float = 2.5,
         rates=(0.0, 0.01, 0.02, 0.05),
         json_path: Optional[str] = None) -> List[str]:
-    if jax is None:  # pragma: no cover
-        return ["faults_skipped,0.0,no jax"]
     from repro.core.lowering import EXECUTABLE_CACHE, BatchedJittedFuse
     from repro.runtime.netmodel import NetModel
     from repro.runtime.runtime import Runtime

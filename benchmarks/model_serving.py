@@ -39,11 +39,8 @@ import numpy as np
 
 from benchmarks.common import percentile, row, run_requests
 
-try:
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
+import jax
+import jax.numpy as jnp
 
 
 def _load_example(name: str):
@@ -237,8 +234,6 @@ def _cascade_section(n_requests: int) -> Dict[str, Any]:
 
 def run(n_requests: int = 30,
         json_path: Optional[str] = None) -> List[str]:
-    if jax is None:  # pragma: no cover
-        return ["model_serving_skipped,0.0,no jax"]
     from repro.core.lowering import EXECUTABLE_CACHE
 
     video = _video_section(n_requests)

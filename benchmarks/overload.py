@@ -51,11 +51,8 @@ from typing import Dict, List, Optional
 
 from benchmarks.common import percentile, row
 
-try:
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
+import jax
+import jax.numpy as jnp
 
 SERVICE_S = 0.01          # per-row service time of the CPU bottleneck
 N_CPU = 2                 # capacity = N_CPU / SERVICE_S = 200 rows/s
@@ -206,8 +203,6 @@ def _drained(rt, timeout_s: float = 10.0):
 def run(duration_s: float = 2.5,
         multipliers=(0.5, 1.0, 2.0, 3.0),
         json_path: Optional[str] = None) -> List[str]:
-    if jax is None:  # pragma: no cover
-        return ["overload_skipped,0.0,no jax"]
     from repro.core.lowering import EXECUTABLE_CACHE, BatchedJittedFuse
     from repro.runtime.netmodel import NetModel
     from repro.runtime.runtime import Runtime

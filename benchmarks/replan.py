@@ -38,11 +38,8 @@ import numpy as np
 
 from benchmarks.common import percentile, row
 
-try:
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
+import jax
+import jax.numpy as jnp
 
 SLO_MS = 50.0
 
@@ -123,8 +120,6 @@ def _drive(dep, rate_hz: float, stop: threading.Event, seed: int = 0):
 
 def run(duration_s: float = 8.0, rate_hz: float = 100.0,
         json_path: Optional[str] = None) -> List[str]:
-    if jax is None:  # pragma: no cover
-        return ["replan_skipped,0.0,no jax"]
     from repro.core.lowering import (EXECUTABLE_CACHE, BatchedJittedFuse,
                                     JittedFuse)
     from repro.profiling import SLOController

@@ -66,6 +66,8 @@ def main() -> None:
     p.add_argument("--json", action="store_true",
                    help="write BENCH_<suite>.json artifacts (batching)")
     args = p.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else set(SUITES)
 
     print("name,us_per_call,derived")

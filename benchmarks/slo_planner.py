@@ -30,11 +30,8 @@ import numpy as np
 
 from benchmarks.common import percentile, row
 
-try:
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
+import jax
+import jax.numpy as jnp
 
 # CPU stage service time; coarse sleep timers land this near 10ms/row in
 # practice (the profiler measures what it actually costs), so the default
@@ -173,8 +170,6 @@ def _measure(cfg, rate_hz: float, n: int) -> Dict[str, float]:
 
 def run(n_requests: int = 150, rates=(60.0, 120.0, 170.0),
         json_path: Optional[str] = None) -> List[str]:
-    if jax is None:  # pragma: no cover
-        return ["slo_planner_skipped,0.0,no jax"]
     from repro.profiling import LatencyEstimator, Workload, profile_plan
     from repro.profiling.optimizer import propose
 

@@ -31,17 +31,21 @@ CACHE = 32
 STEPS = 4
 
 
-def build_ops(*, arch=ARCH, seq_len=SEQ, cache_len=CACHE, measure=True):
-    """(model, params, prefill op, decode op).  The decode op is ONE
-    instance reused at every cascade position, so recompiles share step
-    function identity (stable chain signatures -> zero retraces)."""
-    cfg = get_tiny_config(arch)
+def build_ops(cfg=None, *, seq_len=SEQ, cache_len=CACHE, measure=True,
+              seed=0):
+    """(model, params, prefill op, decode op) for ``cfg`` (default: the
+    tiny ``ARCH``) with random weights from ``seed``.  Weights are made in
+    one jitted call, so a full-width model is built on the device without
+    float32 temporaries of every tensor.  The decode op is ONE instance
+    reused at every cascade position, so recompiles share step function
+    identity (stable chain signatures -> zero retraces)."""
+    cfg = cfg or get_tiny_config(ARCH)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    pre = model_stage_op(model, params, "prefill", model_name=arch,
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed))
+    pre = model_stage_op(model, params, "prefill", model_name=cfg.name,
                          seq_len=seq_len, cache_len=cache_len,
                          measure=measure)
-    dec = model_stage_op(model, params, "decode", model_name=arch,
+    dec = model_stage_op(model, params, "decode", model_name=cfg.name,
                          seq_len=seq_len, cache_len=cache_len,
                          measure=measure)
     return model, params, pre, dec
@@ -57,6 +61,7 @@ def build_flow(pre, dec, *, steps=STEPS):
 
 
 def build(rt, pre, dec, *, steps=STEPS, name="decode-cascade"):
+    """Compile the cascade onto ``rt``; returns the deployed flow."""
     return compile_flow(build_flow(pre, dec, steps=steps), rt,
                         fusion=True, name=name)
 

@@ -27,10 +27,7 @@ from repro.core import operators as ops
 from repro.core.ir import SOURCE_ID, PhysicalPlan
 from repro.core.lowering import BatchedJittedFuse, array_annotation
 
-try:                                    # mirrors core.lowering's guard
-    import jax
-except Exception:                       # pragma: no cover
-    jax = None
+import jax
 
 #: exception types that mean "the step cannot be traced" (data-dependent
 #: python control flow, concretization of tracers) as opposed to a plain
@@ -67,7 +64,7 @@ class EdgeType:
 def specs_from_table(table) -> Optional[Dict[str, object]]:
     """Derive row-level input specs from a sample request table (row 0's
     values).  Non-numeric columns map to None (shape unknown)."""
-    if jax is None or not getattr(table, "rows", None):
+    if not getattr(table, "rows", None):
         return None
     out: Dict[str, object] = {}
     row = table.rows[0]
@@ -120,7 +117,7 @@ def _eval_step(step, in_specs, *, vmapped: bool = False):
 def _steps_analyzable(steps, in_specs) -> bool:
     """All step annotations are jax arrays and every input column has a
     known spec — the precondition for abstract interpretation."""
-    if jax is None or in_specs is None or any(s is None for s in in_specs):
+    if in_specs is None or any(s is None for s in in_specs):
         return False
     for s in steps:
         # a fused chain can carry non-Map/Filter sub-ops (e.g. a Lookup
@@ -209,7 +206,7 @@ def infer(plan: PhysicalPlan,
         return types, report
 
     src_specs = None
-    if input_specs is not None and jax is not None:
+    if input_specs is not None:
         src_specs = tuple(input_specs.get(name)
                           for name, _t in plan.input_schema)
     types[SOURCE_ID] = EdgeType(schema=tuple(plan.input_schema),

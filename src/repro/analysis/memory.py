@@ -15,7 +15,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.analysis.diagnostics import Diagnostic
-from repro.analysis.infer import EdgeType, _chain_of, _eval_step, jax
+from repro.analysis.infer import EdgeType, _chain_of, _eval_step
 from repro.core.ir import PhysicalPlan
 from repro.core.lowering import BatchedJittedFuse, bucket_rows
 
@@ -34,8 +34,6 @@ def chain_peak_row_bytes(steps, in_specs) -> Optional[int]:
     """Peak live bytes per ROW through a fused chain: at every step the
     step's inputs and outputs are live simultaneously (donation can at
     best alias one of them — we bound, not model, the allocator)."""
-    if jax is None:
-        return None
     cur = list(in_specs)
     if any(s is None for s in cur):
         return None

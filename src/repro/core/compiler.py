@@ -144,12 +144,20 @@ class DeployedFlow:
         return list(self.dag.nodes)
 
     def explain(self) -> str:
-        """Human-readable compile report: plan + per-pass trace, plus —
-        when the runtime's tracer holds kept traces for this flow — the
-        per-node SLO-miss attribution table (where the milliseconds of
-        the interesting requests actually went)."""
+        """Human-readable compile report: plan + per-pass trace, the
+        lowered chains that latched onto a fallback path (and the error
+        that made them), plus — when the runtime's tracer holds kept
+        traces for this flow — the per-node SLO-miss attribution table
+        (where the milliseconds of the interesting requests actually
+        went)."""
         lines = [repr(self.plan), ""]
         lines += [repr(t) for t in self.pass_trace]
+        latched = [(o.op.name, kind, why) for o in self.plan.ops
+                   for kind, why in getattr(o.op, "latched", {}).items()]
+        if latched:
+            lines += ["", "-- lowering fallbacks (latched) --"]
+            lines += [f"  {name}: {kind} after {why}"
+                      for name, kind, why in latched]
         tracer = getattr(self.runtime, "tracer", None)
         if tracer is not None and tracer.enabled:
             kept = tracer.kept(self.dag.name)

@@ -51,6 +51,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -58,29 +59,22 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core import operators as ops
-from repro.core.table import (HOST_COPIES, DeviceTable, Table,
-                              note_host_copy)
+from repro.core.table import DeviceTable, Table, note_host_copy
+from repro.obs import keys as okeys
+from repro.obs.metrics import EVENTS
 
-try:  # the container bakes jax in, but keep the core importable without it
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
-    jnp = None
+import jax
+import jax.numpy as jnp
 
 #: annotation types treated as "JAX array" for lowering.  Deliberately NOT
 #: np.ndarray: the jitted chain emits jax.Array values, so only fns that
 #: already declare jax.Array keep their downstream value types unchanged.
-_ARRAY_TYPES: Tuple[type, ...] = ()
-if jax is not None:
-    _ARRAY_TYPES = (jax.Array,)
+_ARRAY_TYPES: Tuple[type, ...] = (jax.Array,)
 
 #: value types jit commits directly (leaf, not pytree) — these skip the
 #: per-column normalization on the per-row hot path
-_FAST_ROW_TYPES: Tuple[type, ...] = (np.ndarray, np.generic, float, int,
-                                     bool, complex)
-if jax is not None:
-    _FAST_ROW_TYPES = (jax.Array,) + _FAST_ROW_TYPES
+_FAST_ROW_TYPES: Tuple[type, ...] = (jax.Array, np.ndarray, np.generic,
+                                     float, int, bool, complex)
 
 
 def _array_annotation(t) -> bool:
@@ -100,7 +94,7 @@ def map_is_jax_lowerable(m: ops.Operator) -> bool:
     """A ``Map`` whose argument and return annotations are all arrays.
     ``m._schema`` already holds the expanded return types (tuple returns
     included) from ``operators._ret_schema``."""
-    if not isinstance(m, ops.Map) or jax is None:
+    if not isinstance(m, ops.Map):
         return False
     arg_types = m._arg_types
     if not arg_types or any(a is None or not _array_annotation(a)
@@ -113,7 +107,7 @@ def filter_is_jax_lowerable(f: ops.Operator) -> bool:
     """A ``Filter`` whose arguments are all arrays and whose predicate is
     declared ``-> bool``: it lowers into the jitted body as a boolean
     mask column (rows compacted only at the device->host boundary)."""
-    if not isinstance(f, ops.Filter) or jax is None:
+    if not isinstance(f, ops.Filter):
         return False
     arg_types, ret = ops.fn_signature(f.fn)
     if ret is not bool:
@@ -142,6 +136,39 @@ def _chain_steps(chain_ops: List[ops.Operator]) -> Tuple[Tuple[str, Any], ...]:
                  for m in chain_ops)
 
 
+def _as_steps(steps) -> Tuple[Tuple[str, Any], ...]:
+    return tuple(s if isinstance(s, tuple) else ("map", s) for s in steps)
+
+
+def _const_slots(steps) -> Tuple[List[Any], List[Optional[int]]]:
+    """(distinct consts, per-step index into them or None).  Steps that
+    share weights (every decode step of a cascade) share one argument."""
+    consts: List[Any] = []
+    slots: List[Optional[int]] = []
+    for _, fn in steps:
+        if getattr(fn, "__pure__", None) is None:
+            slots.append(None)
+            continue
+        c = fn.__consts__
+        i = next((j for j, d in enumerate(consts) if d is c), None)
+        if i is None:
+            i = len(consts)
+            consts.append(c)
+        slots.append(i)
+    return consts, slots
+
+
+def chain_consts(steps) -> Tuple[Any, ...]:
+    """The arrays the chain's steps compute with but do not take as
+    columns (a model stage's weights), each distinct object once.  A step
+    declares them as ``__consts__`` next to ``__pure__(consts, *cols)``;
+    its plain call is ``__pure__`` with the consts bound.  Executables
+    take them as arguments: arrays a traced function merely closes over
+    become constants of the compiled program, and gigabytes of weights as
+    constants exhaust the host's memory while lowering."""
+    return tuple(_const_slots(_as_steps(steps))[0])
+
+
 def compose_steps(steps, *, masked_input: bool, with_keep: bool,
                   counter: Optional[List[int]] = None) -> Callable:
     """The ONE definition of chain composition, shared by the per-row and
@@ -149,27 +176,30 @@ def compose_steps(steps, *, masked_input: bool, with_keep: bool,
     semantics must be identical): apply maps in sequence, AND every
     filter's predicate into the keep bit.
 
-    ``masked_input`` — the callable takes the keep mask as its first
-    argument (device-resident batches thread an upstream mask through);
-    ``with_keep`` — prepend the final keep to the outputs (always true
-    when ``masked_input``); ``counter`` — trace counter, bumped once per
+    The callable's first argument is the tuple of :func:`chain_consts`;
+    then, with ``masked_input``, the keep mask (device-resident batches
+    thread an upstream mask through); then the columns.  ``with_keep`` —
+    prepend the final keep to the outputs (always true when
+    ``masked_input``); ``counter`` — trace counter, bumped once per
     (re-)trace, never per compiled call.
     """
-    steps = tuple(s if isinstance(s, tuple) else ("map", s) for s in steps)
+    steps = _as_steps(steps)
+    slots = _const_slots(steps)[1]
     emit_keep = masked_input or with_keep
 
-    def composed(*args):
+    def composed(consts, *args):
         if counter is not None:
             counter[0] += 1
         if masked_input:
             keep, vals = args[0], args[1:]
         else:
             keep, vals = jnp.bool_(True), args
-        for kind, fn in steps:
+        for (kind, fn), slot in zip(steps, slots):
+            out = (fn(*vals) if slot is None
+                   else fn.__pure__(consts[slot], *vals))
             if kind == "filter":
-                keep = jnp.logical_and(keep, fn(*vals))
+                keep = jnp.logical_and(keep, out)
             else:
-                out = fn(*vals)
                 vals = out if isinstance(out, tuple) else (out,)
         return ((keep,) + tuple(vals)) if emit_keep else tuple(vals)
 
@@ -189,20 +219,22 @@ class JittedFuse(ops.Fuse):
     """
 
     def __post_init__(self):
-        if jax is None:  # pragma: no cover
-            raise RuntimeError("JittedFuse requires jax")
         steps = _chain_steps(self.ops)
         self._steps = steps
         self._has_filter = any(k == "filter" for k, _ in steps)
         self._sig = chain_signature(self.ops)
-        self._jitted = jax.jit(compose_steps(
-            steps, masked_input=False, with_keep=self._has_filter))
+        self._jitted = functools.partial(
+            jax.jit(compose_steps(steps, masked_input=False,
+                                  with_keep=self._has_filter)),
+            chain_consts(steps))
         last_map = next((m for m in reversed(self.ops)
                          if isinstance(m, ops.Map)), None)
         self._out_arity = (len(last_map._schema) if last_map is not None
                            else len(self.ops[0]._arg_types))
         self._fallback = False
         self._jit_succeeded = False
+        #: latch kind -> the error that set it (``explain()`` prints it)
+        self.latched: Dict[str, str] = {}
         self.row_dispatches = 0     # jitted per-row XLA dispatches issued
         self._prof: Optional[ChainProfile] = None
         self._prof_version = -1
@@ -221,6 +253,18 @@ class JittedFuse(ops.Fuse):
     @property
     def name(self):
         return "jit[" + ",".join(o.name for o in self.ops) + "]"
+
+    def _latch(self, kind: str, err: BaseException) -> None:
+        """Give up an executable for the rest of the deployment (``fuse``:
+        back to the interpreted ``Fuse``; ``vmap``: per-row jit only),
+        counted in ``repro.obs.EVENTS`` and kept for ``explain()`` — a
+        kernel the device's compiler refuses must not vanish silently."""
+        if kind == "fuse":
+            self._fallback = True
+        else:
+            self._vmap_fallback = True
+        self.latched[kind] = f"{type(err).__name__}: {err}"[:300]
+        EVENTS.inc(okeys.lowering_latch(kind))
 
     @property
     def jitted_fn(self):
@@ -275,7 +319,8 @@ class JittedFuse(ops.Fuse):
                     rows.append(out)
         except ops.TypecheckError:
             raise
-        except (jax.errors.JAXTypeError, TypeError, NotImplementedError):
+        except (jax.errors.JAXTypeError, TypeError,
+                NotImplementedError) as e:
             # annotations said "array" but the fn is not jax-traceable
             # (data-dependent control flow, numpy side effects, ...).
             # Tracing happens on the first call, so only latch the
@@ -285,7 +330,7 @@ class JittedFuse(ops.Fuse):
             # of silently disabling the jitted path for the deployment.
             if self._jit_succeeded:
                 raise
-            self._fallback = True
+            self._latch("fuse", e)
             return ops.Fuse.apply(self, tables, ctx)
         if timed and rows:
             # feed the exec-path router: measured warm per-row cost (cold
@@ -605,11 +650,13 @@ class ExecutableCache:
 
     def executable(self, sig: Tuple, steps, shapes: Tuple, dtypes: Tuple,
                    *, masked: bool = False, donate: bool = False) -> Callable:
-        """The compiled callable for this (chain, bucket shapes, dtypes).
+        """The compiled callable for this (chain, bucket shapes, dtypes),
+        with the chain's :func:`chain_consts` bound as its leading
+        argument.
 
         ``shapes``/``dtypes`` describe the value columns only; the masked
         variant takes the boolean liveness column as its first argument.
-        With ``donate=True`` every input buffer is donated to XLA
+        With ``donate=True`` every column buffer is donated to XLA
         (``donate_argnums``) — callers must own them exclusively."""
         with self._lock:
             rec = self._fns.get(sig)
@@ -635,9 +682,12 @@ class ExecutableCache:
                                          with_keep=masked,
                                          counter=rec["counter"])
                 n_args = len(shapes) + (1 if masked else 0)
-                fn = jax.jit(jax.vmap(composed),
-                             donate_argnums=(tuple(range(n_args))
-                                             if donate else ()))
+                fn = functools.partial(
+                    jax.jit(jax.vmap(composed,
+                                     in_axes=(None,) + (0,) * n_args),
+                            donate_argnums=(tuple(range(1, n_args + 1))
+                                            if donate else ())),
+                    chain_consts(steps))
                 rec["jitted"][variant] = fn
             key = (sig, shapes, dtypes) + variant
             if key in self._entries:
@@ -836,16 +886,16 @@ class BatchedJittedFuse(JittedFuse):
         except ops.TypecheckError:
             raise
         except (jax.errors.JAXTypeError, TypeError, NotImplementedError,
-                ValueError):
+                ValueError) as e:
             if self._batch_succeeded and self._jit_succeeded:
                 raise
             if self._jit_succeeded:
-                self._vmap_fallback = True
+                self._latch("vmap", e)
                 self.host_gathers += 1
                 return JittedFuse.apply(self, [dt.to_table()], ctx)
             if self._batch_succeeded:
                 raise
-            self._fallback = True
+            self._latch("fuse", e)
             self.host_gathers += 1
             return ops.Fuse.apply(self, [dt.to_table()], ctx)
         self._batch_succeeded = True
@@ -939,7 +989,7 @@ class BatchedJittedFuse(JittedFuse):
         except ops.TypecheckError:
             raise
         except (jax.errors.JAXTypeError, TypeError, NotImplementedError,
-                ValueError):
+                ValueError) as e:
             # latching policy mirrors the per-row path, but the two
             # executables are judged separately: a chain can be jit-traceable
             # per row yet fail under vmap (callbacks, batching-hostile
@@ -949,13 +999,13 @@ class BatchedJittedFuse(JittedFuse):
                 raise
             if self._jit_succeeded:
                 # per-row proven; the vmapped path is the suspect
-                self._vmap_fallback = True
+                self._latch("vmap", e)
                 return JittedFuse.apply(self, tables, ctx)
             if self._batch_succeeded:
                 # vmap proven but the per-row (singleton) call failed:
                 # composed fn traced fine under vmap, so treat as data error
                 raise
-            self._fallback = True
+            self._latch("fuse", e)
             return ops.Fuse.apply(self, tables, ctx)
         if vmapped_any:
             # a singleton-only table proves the per-row executable, not the

@@ -24,12 +24,8 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-try:  # keep the core importable without jax (DeviceTable then unusable)
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
-    jnp = None
+import jax
+import jax.numpy as jnp
 
 Schema = List[Tuple[str, type]]
 
@@ -214,8 +210,6 @@ class DeviceTable:
         is padded up to ``pad_to`` by repeating row 0 so device shapes stay
         bucket-sized; padding rows carry no mask entry — ``nrows`` bounds
         the live range."""
-        if jnp is None:  # pragma: no cover
-            raise RuntimeError("DeviceTable requires jax")
         n = len(row_ids)
         cap = max(pad_to or n, n)
         columns = []

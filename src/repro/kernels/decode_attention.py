@@ -17,14 +17,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
+def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, scale: float, window: int,
                    softcap: float, bs: int, ns: int):
+    b = pl.program_id(0)
     si = pl.program_id(2)
 
     @pl.when(si == 0)
@@ -36,8 +35,8 @@ def _decode_kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)              # [G, hd]
     k = k_ref[0, 0].astype(jnp.float32)              # [bs, hd]
     v = v_ref[0, 0].astype(jnp.float32)
-    kpos = kpos_ref[0, 0]                            # [bs]
-    qpos = qpos_ref[0, 0]                            # scalar int32
+    kpos = kpos_ref[0]                               # [1, bs]
+    qpos = qpos_ref[b]                               # scalar int32 (SMEM)
 
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
@@ -47,7 +46,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
     valid = (kpos >= 0) & (kpos <= qpos)
     if window > 0:
         valid &= (qpos - kpos) < window
-    logits = jnp.where(valid[None, :], logits, NEG_INF)
+    logits = jnp.where(valid, logits, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
@@ -80,30 +79,37 @@ def decode_attention(q, k_cache, v_cache, k_positions, q_position, *,
     assert S % bs == 0
     ns = S // bs
     qg = q.reshape(B, K, G, hd)
-    kpos = jnp.broadcast_to(k_positions[:, None], (B, K, S))
-    qpos = jnp.broadcast_to(q_position[:, None], (B, K)).astype(jnp.int32)
+    # positions shared by the K kv heads: [B, 1, S], so the block's last
+    # two dims (1, bs) are (whole dim, lane-aligned) as Mosaic requires;
+    # the query position is one scalar per row, prefetched into SMEM
+    kpos = k_positions.astype(jnp.int32)[:, None, :]
+    qpos = q_position.astype(jnp.int32)
 
     kernel = functools.partial(_decode_kernel, scale=scale, window=window,
                                softcap=softcap, bs=bs, ns=ns)
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, K, ns),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, kh, si: (b, kh, 0, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, kh, si: (b, kh, si, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, kh, si: (b, kh, si, 0)),
-            pl.BlockSpec((1, 1, bs), lambda b, kh, si: (b, kh, si)),
-            pl.BlockSpec((1, 1), lambda b, kh, si: (b, kh)),
+            pl.BlockSpec((1, 1, G, hd), lambda b, kh, si, _: (b, kh, 0, 0)),
+            pl.BlockSpec((1, 1, bs, hd), lambda b, kh, si, _: (b, kh, si, 0)),
+            pl.BlockSpec((1, 1, bs, hd), lambda b, kh, si, _: (b, kh, si, 0)),
+            pl.BlockSpec((1, 1, bs), lambda b, kh, si, _: (b, 0, si)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, kh, si: (b, kh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, hd),
+                               lambda b, kh, si, _: (b, kh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qg, k_cache, v_cache, kpos, qpos)
+    )(qpos, qg, k_cache, v_cache, kpos)
     return out.reshape(B, H, hd)

@@ -15,15 +15,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _rglru_kernel(a_ref, x_ref, h0_ref, y_ref, h_ref, *, ct: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
-        h_ref[...] = h0_ref[0].astype(jnp.float32)
+        h_ref[...] = h0_ref[0, 0].astype(jnp.float32)
 
     def step(t, h):
         a = a_ref[0, t].astype(jnp.float32)        # [Rb]
@@ -47,6 +45,10 @@ def rglru_scan(a, x, h0=None, *, chunk: int = 128, block_r: int = 512,
     assert T % ct == 0 and R % br == 0
     nc, nr = T // ct, R // br
 
+    # float32 in HBM: the kernel reads one timestep row at a time, and
+    # Mosaic cannot load a single row at a dynamic offset from packed
+    # (bf16) tiles
+    a, x = a.astype(jnp.float32), x.astype(jnp.float32)
     kernel = functools.partial(_rglru_kernel, ct=ct)
     y = pl.pallas_call(
         kernel,
@@ -54,13 +56,13 @@ def rglru_scan(a, x, h0=None, *, chunk: int = 128, block_r: int = 512,
         in_specs=[
             pl.BlockSpec((1, ct, br), lambda b, r, c: (b, c, r)),
             pl.BlockSpec((1, ct, br), lambda b, r, c: (b, c, r)),
-            pl.BlockSpec((1, br), lambda b, r, c: (b, r)),
+            pl.BlockSpec((1, 1, br), lambda b, r, c: (b, 0, r)),
         ],
         out_specs=pl.BlockSpec((1, ct, br), lambda b, r, c: (b, c, r)),
         out_shape=jax.ShapeDtypeStruct((B, T, R), jnp.float32),
         scratch_shapes=[pltpu.VMEM((br,), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(a, x, h0)
+    )(a, x, h0[:, None, :])
     return y

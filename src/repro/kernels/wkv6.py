@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_ref, *,
                 ct: int, nc: int):
@@ -26,7 +24,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_ref, *,
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    u = u_ref[0].astype(jnp.float32)                   # [hd]
+    u = u_ref[0, 0].astype(jnp.float32)                # [hd]
 
     def step(t, S):
         rt = r_ref[0, 0, t].astype(jnp.float32)        # [hd]
@@ -51,9 +49,13 @@ def wkv6(r, k, v, w, u, *, chunk: int = 64, interpret: bool = False):
     ct = min(chunk, T)
     assert T % ct == 0
     nc = T // ct
-    # layout [B, H, T, hd] so the chunk dim tiles cleanly
+    # layout [B, H, T, hd] so the chunk dim tiles cleanly; u as [H, 1, hd]
+    # so its block's last two dims equal the array's.  Float32 in HBM: the
+    # kernel reads one timestep row at a time, and Mosaic cannot load a
+    # single row at a dynamic offset from packed (bf16) tiles
     perm = (0, 2, 1, 3)
-    rt, kt, vt, wt = (x.transpose(perm) for x in (r, k, v, w))
+    rt, kt, vt, wt = (x.astype(jnp.float32).transpose(perm)
+                      for x in (r, k, v, w))
 
     kernel = functools.partial(_wkv_kernel, ct=ct, nc=nc)
     y = pl.pallas_call(
@@ -64,13 +66,13 @@ def wkv6(r, k, v, w, u, *, chunk: int = 64, interpret: bool = False):
             pl.BlockSpec((1, 1, ct, hd), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, ct, hd), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, ct, hd), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, hd), lambda b, h, c: (h, 0)),
+            pl.BlockSpec((1, 1, hd), lambda b, h, c: (h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, ct, hd), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, T, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(rt, kt, vt, wt, u)
+    )(rt, kt, vt, wt, u[:, None, :])
     return y.transpose(perm)

@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs import get_tiny_config, ARCH_IDS
 from repro.core.dataflow import Dataflow
 from repro.core.table import Table
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.netmodel import NetModel
 from repro.runtime.runtime import Runtime
 from repro.serving.engine import make_engine
@@ -57,6 +58,7 @@ def main():
     p.add_argument("--requests", type=int, default=8)
     p.add_argument("--new-tokens", type=int, default=8)
     args = p.parse_args()
+    enable_compile_cache()
     flow, _ = build_flow(args.arch, max_new_tokens=args.new_tokens)
     rt = Runtime(n_cpu=2, net=NetModel(scale=0.0))
     flow.deploy(rt, fusion=True)

@@ -16,22 +16,13 @@ optimization applied to the MoE hot-spot (DESIGN.md §2/§6).
 from __future__ import annotations
 
 import functools
-import inspect
 import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-try:                                   # jax >= 0.6: top-level name
-    from jax import shard_map
-except ImportError:                    # jax 0.4.x/0.5.x
-    from jax.experimental.shard_map import shard_map
-
-# replication-check kwarg was renamed check_rep -> check_vma across jax
-_SHARD_MAP_CHECK_KW = (
-    "check_vma" if "check_vma" in inspect.signature(shard_map).parameters
-    else "check_rep")
 
 from repro.configs.base import ModelConfig
 from repro.models import layers
@@ -245,7 +236,7 @@ def moe_apply_ep(x, params, cfg: ModelConfig, ax: AxisInfo, *,
                 w_spec(params["w_up"]), w_spec(params["w_down"]))
     out_specs = (P(dp, seq_spec, None), P())
     fn_s = shard_map(fn, mesh=ax.mesh, in_specs=in_specs,
-                     out_specs=out_specs, **{_SHARD_MAP_CHECK_KW: False})
+                     out_specs=out_specs, check_vma=False)
     w_gate = params.get("w_gate")
     if w_gate is None:
         w_gate = params["w_up"]  # placeholder, unused when not gated

@@ -183,6 +183,12 @@ def build_model(cfg: ModelConfig, ax: Optional[AxisInfo] = None, *,
 # The KV cache rides the table as per-row columns (one per pytree leaf),
 # so prefill -> decode -> decode chains fuse into a single device-resident
 # chain with no host round-trip between steps.
+#
+# The weights are not closed over: the step carries them as
+# ``__consts__`` beside ``__pure__(params, *cols)``, and a lowered chain
+# passes them to its executable as an argument
+# (``repro.core.lowering.chain_consts``).  Closed over, a full-width
+# model's gigabytes would be lowered as constants of every program.
 
 def _stage_fn(fname: str, argnames, inner, ret_arity: int):
     """Explicit-positional-arg wrapper (``fn_signature`` reads
@@ -206,34 +212,38 @@ def _stage_fn(fname: str, argnames, inner, ret_arity: int):
 
 
 def _rowwise_native_batch(batched, multi: bool):
-    """Row-wise view of a natively-batched stage fn: untransformed calls
-    run the stage with B=1; under ``jax.vmap`` (a batched-lowered chain)
-    the rule feeds the whole row batch to the stage in one call."""
+    """Row-wise view of a natively-batched stage fn ``batched(params,
+    *cols)``: untransformed calls run the stage with B=1; under
+    ``jax.vmap`` (a batched-lowered chain, params unbatched) the rule
+    feeds the whole row batch to the stage in one call."""
 
     @jax.custom_batching.custom_vmap
-    def per_row(*cols):
-        out = batched(*[c[None] for c in cols])
+    def per_row(params, *cols):
+        out = batched(params, *[c[None] for c in cols])
         return tuple(o[0] for o in out) if multi else out[0]
 
     @per_row.def_vmap
-    def _rule(axis_size, in_batched, *cols):
+    def _rule(axis_size, in_batched, params, *cols):
+        if any(jax.tree_util.tree_leaves(in_batched[0])):
+            raise ValueError("model weights cannot be batched")
         cols = [c if b
                 else jnp.broadcast_to(c[None], (axis_size,) + c.shape)
-                for c, b in zip(cols, in_batched)]
-        out = batched(*cols)
+                for c, b in zip(cols, in_batched[1:])]
+        out = batched(params, *cols)
         return (out, tuple(True for _ in out)) if multi else (out, True)
 
     return per_row
 
 
-def _timing_hook(batched, arg_maker, *, runs: int = 3, warmup: int = 1):
+def _timing_hook(batched, params, arg_maker, *, runs: int = 3,
+                 warmup: int = 1):
     """Per-bucket cost hook: measure the jitted natively-batched stage at
     batch size ``b``.  Feeds ``profiling.profiler.seed_from_model_ops`` ->
     ``OpLatencyCurve`` buckets."""
     import statistics
     import time
 
-    jitted = jax.jit(batched)
+    jitted = functools.partial(jax.jit(batched), params)
 
     def hook(b: int) -> Dict[str, Any]:
         args = arg_maker(b)
@@ -302,45 +312,37 @@ def model_stage_op(model: Model, params, stage: str, *,
                       for l, ax in zip(leaves, batch_axes)])
 
     if stage == "logits":
-        def batched(tokens):
+        def batched(params, tokens):
             out, _ = model.logits(params, {"tokens": tokens}, remat=False)
             return out[:, -1]
 
-        fn = _stage_fn(f"{model_name}_logits", ("tokens",),
-                       _rowwise_native_batch(batched, multi=False), 1)
-        names = ["logits"]
+        argnames, names = ("tokens",), ["logits"]
 
         def arg_maker(b):
             return (jnp.zeros((b, seq_len), i32),)
 
     elif stage == "prefill":
-        def batched(tokens):
+        def batched(params, tokens):
             logits, cache = model.prefill(params, {"tokens": tokens},
                                           cache_len)
             tok = jnp.argmax(logits[:, -1], axis=-1).astype(i32)
             pos = jnp.full(tokens.shape[:1], tokens.shape[1], i32)
             return (tok, pos, *_split(cache))
 
-        fn = _stage_fn(f"{model_name}_prefill", ("tokens",),
-                       _rowwise_native_batch(batched, multi=True),
-                       2 + n_leaves)
-        names = list(state_names)
+        argnames, names = ("tokens",), list(state_names)
 
         def arg_maker(b):
             return (jnp.zeros((b, seq_len), i32),)
 
     elif stage == "decode":
-        def batched(tok, pos, *leaves):
+        def batched(params, tok, pos, *leaves):
             cache = _join(leaves)
             logits, new_cache = model.decode_step(params, tok[:, None],
                                                   pos, cache)
             ntok = jnp.argmax(logits[:, -1], axis=-1).astype(i32)
             return (ntok, pos + 1, *_split(new_cache))
 
-        fn = _stage_fn(f"{model_name}_decode", tuple(state_names),
-                       _rowwise_native_batch(batched, multi=True),
-                       2 + n_leaves)
-        names = list(state_names)
+        argnames, names = tuple(state_names), list(state_names)
 
         def arg_maker(b):
             cache = model.init_cache(b, cache_len)
@@ -351,7 +353,13 @@ def model_stage_op(model: Model, params, stage: str, *,
         raise ValueError(f"unknown stage {stage!r} "
                          "(logits | prefill | decode)")
 
-    hook = _timing_hook(batched, arg_maker, runs=runs) if measure else None
+    multi = stage != "logits"
+    pure = _rowwise_native_batch(batched, multi=multi)
+    fn = _stage_fn(f"{model_name}_{stage}", argnames,
+                   functools.partial(pure, params), len(names))
+    fn.__pure__, fn.__consts__ = pure, params
+    hook = (_timing_hook(batched, params, arg_maker, runs=runs)
+            if measure else None)
     return ops.ModelOp(fn=fn, names=names, model_name=model_name,
                        stage=stage, cost_hook=hook)
 

@@ -13,7 +13,8 @@ a human — can answer "why did this request miss its deadline?":
   traces; bounded ring buffer of kept traces.
 * :mod:`repro.obs.metrics` — log-bucketed mergeable ``Histogram`` and
   time-``WindowedCounter``, the bounded replacements for unbounded
-  per-key value lists.
+  per-key value lists; ``EVENTS``, the process's totals of events no
+  runtime owns (lowering fallbacks).
 * :mod:`repro.obs.export` — JSON and Chrome trace-event
   (``chrome://tracing`` / Perfetto) export of kept traces.
 * :mod:`repro.obs.attribution` — folds kept traces into a per-node
@@ -32,12 +33,14 @@ from repro.obs.attribution import Attribution, NodeBreakdown, attribute
 from repro.obs.clock import now
 from repro.obs.export import (export_chrome, to_chrome_events, to_json,
                               write_chrome)
-from repro.obs.metrics import Histogram, HistogramSnapshot, WindowedCounter
+from repro.obs.metrics import (EVENTS, EventCounts, Histogram,
+                               HistogramSnapshot, WindowedCounter)
 from repro.obs.trace import Span, Trace, Tracer
 
 __all__ = [
     "Attribution", "NodeBreakdown", "attribute", "keys", "now",
     "export_chrome", "to_chrome_events", "to_json", "write_chrome",
-    "Histogram", "HistogramSnapshot", "WindowedCounter",
+    "EVENTS", "EventCounts", "Histogram", "HistogramSnapshot",
+    "WindowedCounter",
     "Span", "Trace", "Tracer",
 ]

@@ -21,6 +21,8 @@ themselves contain ``/``):
   plus :data:`FAULT_REQUEUED`
 * ``replan/rollback`` — blue/green swap-backs
   (:data:`REPLAN_ROLLBACK`)
+* ``lowering/latch/{kind}`` — a lowered chain gave up an executable
+  (:data:`LOWERING_LATCHES`; counted in :data:`repro.obs.EVENTS`)
 
 ``*_t`` series are event timestamps (windowed counters); the rest are
 value histograms (``Runtime.record_metric`` routes on the suffix).
@@ -101,6 +103,21 @@ def fault(kind: str) -> str:
     return f"faults/{kind}_t"
 
 
+# -- lowering fallbacks -----------------------------------------------------
+
+#: a lowered chain whose first call fails latches onto a slower path for
+#: the rest of the deployment: ``fuse`` — the interpreted ``Fuse`` (no
+#: device executable at all); ``vmap`` — the batched executable is
+#: dropped and the per-row jitted one serves
+LOWERING_LATCHES = ("fuse", "vmap")
+
+
+def lowering_latch(kind: str) -> str:
+    if kind not in LOWERING_LATCHES:
+        raise ValueError(f"unknown lowering latch {kind!r}")
+    return f"lowering/latch/{kind}"
+
+
 # -- the registry lint ------------------------------------------------------
 
 _PATTERNS: List[re.Pattern] = [
@@ -111,6 +128,7 @@ _PATTERNS: List[re.Pattern] = [
     re.compile(r"\Afaults/(" + "|".join(FAULT_KINDS) + r")_t\Z"),
     re.compile(re.escape(FAULT_REQUEUED) + r"\Z"),
     re.compile(re.escape(REPLAN_ROLLBACK) + r"\Z"),
+    re.compile(r"\Alowering/latch/(" + "|".join(LOWERING_LATCHES) + r")\Z"),
 ]
 
 

@@ -18,12 +18,20 @@ shapes of series get the two right structures:
 
 Both are lock-free at this layer (callers serialize; the runtime records
 under its metrics lock) and strictly bounded in memory.
+
+:class:`EventCounts` holds plain event totals for state no runtime owns
+— lowered chains are shared by every runtime in the process through the
+executable cache — and :data:`EVENTS` is the process's instance.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
+import threading
 from typing import Dict, List, Optional, Sequence
+
+from repro.obs.keys import known_key
 
 
 class Histogram:
@@ -189,3 +197,27 @@ class WindowedCounter:
 
     def rate(self, window_s: float, now: float) -> float:
         return self.count(window_s, now) / max(window_s, 1e-9)
+
+
+class EventCounts:
+    """Thread-safe totals of named events.  Keys must be registered in
+    :mod:`repro.obs.keys`, so a typo fails where it is counted."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._counts: "collections.Counter[str]" = collections.Counter()
+
+    def inc(self, key: str, n: int = 1) -> None:
+        if not known_key(key):
+            raise ValueError(f"unregistered event key {key!r}")
+        with self._lock:
+            self._counts[key] += n
+
+    def snapshot(self, prefix: str = "") -> Dict[str, int]:
+        with self._lock:
+            return {k: v for k, v in self._counts.items()
+                    if k.startswith(prefix)}
+
+
+#: the process's event totals (read by ``chip_smoke.py`` and tests)
+EVENTS = EventCounts()

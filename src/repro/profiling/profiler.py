@@ -34,10 +34,7 @@ from repro.core.ir import SOURCE_ID, PhysicalPlan
 from repro.core.table import DeviceTable, Row, Table
 from repro.runtime.netmodel import nbytes
 
-try:  # keep importable without jax (profiling then skips device syncs)
-    import jax
-except Exception:  # pragma: no cover
-    jax = None
+import jax
 
 #: default batch sizes swept per op — aligned with the lowering's
 #: power-of-two padding buckets so the curve measures the shapes the
@@ -247,8 +244,6 @@ def _replicate(sample: Table, b: int) -> Table:
 def _sync(out) -> None:
     """Block until device work behind ``out`` is done — async backends
     return immediately and an unsynced timing would undercount."""
-    if jax is None:
-        return
     try:
         if isinstance(out, DeviceTable):
             jax.block_until_ready(out.columns)

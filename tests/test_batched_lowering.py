@@ -200,6 +200,8 @@ def test_vmap_failure_after_singleton_success_degrades_to_per_row():
     # multi-row batch: vmap trace fails -> degrade to per-row, not raise
     out = plan.execute_local(_table([jnp.ones(4), jnp.ones(4) * 2]))
     assert op._vmap_fallback and not op._fallback
+    assert list(op.latched) == ["vmap"]
+    assert "no vmap for me" in op.latched["vmap"]
     np.testing.assert_allclose(np.asarray(out.rows[0].values[0]),
                                np.full(4, 4.0))
     # and it stays on the per-row path afterwards
@@ -503,3 +505,35 @@ def test_planner_decides_batched_lowering_from_hints():
     if plan.jit_fusion:
         assert plan.batched_lowering          # batch hint present
     assert "batched_lowering" in plan.flags
+
+
+def test_latch_counts_in_obs_and_shows_in_explain():
+    """A latch onto the interpreted path is counted in
+    ``repro.obs.EVENTS`` under its registered key and named, with the
+    error behind it, by the deployment's ``explain()``."""
+    from repro.obs import EVENTS, keys as okeys
+    from repro.runtime import NetModel, Runtime
+
+    def branchy(x: jax.Array) -> jax.Array:
+        return x + 1 if float(x.sum()) > 0 else x - 1   # not traceable
+
+    def double(x: jax.Array) -> jax.Array:
+        return x * 2
+
+    fl = Dataflow([("x", jax.Array)])
+    fl.output = fl.map(branchy, names=["x"], gpu=True).map(
+        double, names=["x"], gpu=True)
+    key = okeys.lowering_latch("fuse")
+    before = EVENTS.snapshot().get(key, 0)
+    rt = Runtime(n_cpu=1, n_gpu=1, net=NetModel(scale=0.0))
+    try:
+        dep = fl.deploy(rt, fusion=True, name="latching")
+        assert "lowering fallbacks" not in dep.explain()
+        out = dep.execute(_table([jnp.ones(4)] * 2)).result(60)
+    finally:
+        rt.stop()
+    np.testing.assert_allclose(np.asarray(out.rows[0].values[0]),
+                               np.full(4, 4.0))
+    assert EVENTS.snapshot().get(key, 0) == before + 1
+    text = dep.explain()
+    assert "lowering fallbacks" in text and "fuse after" in text

@@ -107,6 +107,39 @@ def test_prefill_decode_stages_match_model_loop(stages):
     assert got_row == want[0]
 
 
+def test_weights_reach_executables_as_arguments(stages, rt):
+    """A served stage's weights are an argument of the chain executable,
+    never constants of the compiled program: lowering a full-width model
+    with its weights captured as constants exhausts the host's memory.
+    JAX warns once captured constants pass the threshold set here, far
+    below the tiny model's weights."""
+    import warnings
+    cfg, _model, params, lg, _, _ = stages
+    assert lg.fn.__consts__ is params
+    fl = Dataflow([("tokens", jax.Array)])
+    fl.output = fl.map(_clip, names=["tokens"], gpu=True).apply_op(
+        lg, gpu=True)
+    dep = compile_flow(fl, rt, fusion=True, name="weights_as_args")
+    tab = Table([("tokens", jax.Array)],
+                [(t,) for t in _toks(cfg, 3)])
+    prev = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", 10_000)
+    try:
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            out = dep.execute(tab).result(120)            # batched
+            dep.execute(Table(tab.schema, tab.rows[:1])).result(120)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", prev)
+    assert len(out.rows) == 3
+    assert not [w for w in caught if "constants were captured"
+                in str(w.message)]
+
+
+def _clip(tokens: jax.Array) -> jax.Array:
+    return jnp.clip(tokens, 0, 10**6)
+
+
 def test_cost_hook_contract(stages):
     cfg, model, params, _, _, _ = stages
     op = model_stage_op(model, params, "logits", model_name=ARCH,

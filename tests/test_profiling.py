@@ -7,13 +7,8 @@ import math
 import numpy as np
 import pytest
 
-try:
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
-
-pytestmark = pytest.mark.skipif(jax is None, reason="requires jax")
+import jax
+import jax.numpy as jnp
 
 from repro.core.dataflow import Dataflow
 from repro.core.ir import PhysicalPlan

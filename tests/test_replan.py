@@ -28,11 +28,8 @@ from repro.runtime.netmodel import NetModel
 from repro.runtime.runtime import Runtime
 from repro.serving.batcher import Batcher
 
-try:
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
+import jax
+import jax.numpy as jnp
 
 
 @pytest.fixture
@@ -350,9 +347,6 @@ def test_failed_replan_cooldown_suppresses_retries():
 # tentpole: blue/green replanning
 # ---------------------------------------------------------------------------
 
-pytestmark_gpu = pytest.mark.skipif(jax is None, reason="requires jax")
-
-
 def _gm1(x: "jax.Array") -> "jax.Array":
     return x * 2.0
 
@@ -372,7 +366,6 @@ def _sample():
     return Table([("x", jax.Array)], [(jnp.ones(8, jnp.float32),)])
 
 
-@pytestmark_gpu
 def test_blue_green_swap_zero_retrace_and_state_carryover():
     from repro.core.lowering import EXECUTABLE_CACHE
     from repro.profiling import BlueGreenReplanner, NodeConfig, PlanConfig
@@ -427,7 +420,6 @@ def test_blue_green_swap_zero_retrace_and_state_carryover():
         rt.stop()
 
 
-@pytestmark_gpu
 def test_blue_green_inflight_requests_finish_on_blue():
     """Requests in flight at swap time complete on the blue generation
     with correct results — zero drops across the swap."""
@@ -463,7 +455,6 @@ def test_blue_green_inflight_requests_finish_on_blue():
         rt.stop()
 
 
-@pytestmark_gpu
 def test_canary_failure_aborts_swap_blue_stays_live():
     from repro.profiling import BlueGreenReplanner, NodeConfig, PlanConfig
 
@@ -507,7 +498,6 @@ def test_canary_failure_aborts_swap_blue_stays_live():
         rt.stop()
 
 
-@pytestmark_gpu
 def test_warm_deployment_pretraces_all_buckets():
     """After warm_deployment, driving every bucket size produces ZERO new
     traces — the first post-swap request is provably trace-free."""
@@ -541,7 +531,6 @@ def test_warm_deployment_pretraces_all_buckets():
         rt.stop()
 
 
-@pytestmark_gpu
 def test_controller_default_replanner_escalates_swaps_and_confirms():
     """The full loop: a per-row-lowered deployment saturates at the
     measured rate -> the optimizer proposes a batched flip (compile-time)
@@ -613,7 +602,6 @@ def _rb2(x: "jax.Array") -> "jax.Array":
     return x - 1.0
 
 
-@pytestmark_gpu
 def test_failed_confirm_rolls_back_to_blue_automatically():
     """Satellite: when the confirm tick after a blue/green swap shows the
     green generation missing the SLO (here: a rising error rate), the

@@ -14,13 +14,8 @@ import time
 import numpy as np
 import pytest
 
-try:
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
-
-pytestmark = pytest.mark.skipif(jax is None, reason="requires jax")
+import jax
+import jax.numpy as jnp
 
 from repro.core.dataflow import Dataflow
 from repro.core.lowering import EXECUTABLE_CACHE, BatchedJittedFuse
