@@ -1,0 +1,9 @@
+"""The benchmark's own tests run on the CPU, at the configurations' tiny
+rehearsal sizes:  ``python -m pytest bench/tests``."""
+import os
+import sys
+from pathlib import Path
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+ROOT = Path(__file__).resolve().parents[2]
+sys.path[:0] = [str(ROOT), str(ROOT / "src")]
