@@ -62,6 +62,7 @@ from repro.core import operators as ops
 from repro.core.table import DeviceTable, Table, note_host_copy
 from repro.obs import keys as okeys
 from repro.obs.metrics import EVENTS
+from repro.obs.trace import region
 
 import jax
 import jax.numpy as jnp
@@ -240,6 +241,7 @@ class JittedFuse(ops.Fuse):
         self._prof_version = -1
         self._timing_tick = 0
         self._force_time = False    # set by a per-row routing probe
+        self._node = self.name      # names this chain's profiler regions
 
     def profile(self) -> "ChainProfile":
         """This chain's measured cost profile (cached handle into the
@@ -271,15 +273,17 @@ class JittedFuse(ops.Fuse):
         """The single compiled callable (one per fused chain)."""
         return self._jitted
 
-    def _row_call(self, r):
+    def _row_call(self, r, path: str = "row"):
         """One per-row jitted dispatch; returns the output Row, or None for
         a row a fused filter dropped.  Array/scalar values go to the
         executable as-is (jit commits them itself — no per-column
         ``jnp.asarray`` on the hot path); anything else (a Python list
         smuggled past an array annotation) is normalized first, because
-        jit would treat it as a pytree and silently compute nonsense."""
-        out = self._jitted(*(v if isinstance(v, _FAST_ROW_TYPES)
-                             else jnp.asarray(v) for v in r.values))
+        jit would treat it as a pytree and silently compute nonsense.
+        ``path`` (``row`` or ``probe``) labels the dispatch's region."""
+        with region("dispatch", node=self._node, path=path, rows=1):
+            out = self._jitted(*(v if isinstance(v, _FAST_ROW_TYPES)
+                                 else jnp.asarray(v) for v in r.values))
         self.row_dispatches += 1
         keep = None
         if self._has_filter:
@@ -310,11 +314,12 @@ class JittedFuse(ops.Fuse):
             timed = self._force_time or \
                 self._timing_tick % TIMING_SAMPLE_EVERY == 0
             self._timing_tick += 1
+        path = "probe" if self._force_time else "row"
         self._force_time = False
         t0 = time.perf_counter()
         try:
             for r in t.rows:
-                out = self._row_call(r)
+                out = self._row_call(r, path)
                 if out is not None:
                     rows.append(out)
         except ops.TypecheckError:
@@ -821,7 +826,12 @@ class BatchedJittedFuse(JittedFuse):
         calls the batching is meant to eliminate."""
         host_vals = [list(r.values) for r in rows]
         if any(isinstance(v, jax.Array) for rv in host_vals for v in rv):
-            host_vals = jax.device_get(host_vals)
+            with region("stack", node=self._node) as reg:
+                if reg:
+                    reg.set_metadata(bytes=sum(
+                        getattr(v, "nbytes", 0) for rv in host_vals
+                        for v in rv))
+                host_vals = jax.device_get(host_vals)
             # honest accounting: this readback IS bulk row payload
             # crossing the boundary (rows arriving as host numpy — the
             # normal serving case — skip it entirely)
@@ -848,15 +858,17 @@ class BatchedJittedFuse(JittedFuse):
         do = bool(donate and dt.donatable)
         fn = EXECUTABLE_CACHE.executable(self._sig, self._steps, shapes,
                                          dtypes, masked=masked, donate=do)
-        if masked:
-            mask = dt.mask
-            if mask is None:
-                mask = jnp.asarray(np.ones(dt.cap, np.bool_))
-            outs = fn(mask, *dt.columns)
-            new_mask, out_cols = outs[0], outs[1:]
-        else:
-            out_cols = fn(*dt.columns)
-            new_mask = None
+        with region("dispatch", node=self._node, path="batch",
+                    rows=dt.nrows, bucket=dt.cap):
+            if masked:
+                mask = dt.mask
+                if mask is None:
+                    mask = jnp.asarray(np.ones(dt.cap, np.bool_))
+                outs = fn(mask, *dt.columns)
+                new_mask, out_cols = outs[0], outs[1:]
+            else:
+                out_cols = fn(*dt.columns)
+                new_mask = None
         if len(out_cols) != self._out_arity:
             raise ops.TypecheckError(
                 f"{self.name}: returned {len(out_cols)} values, schema "
@@ -877,10 +889,10 @@ class BatchedJittedFuse(JittedFuse):
         table, at the chain boundary) out — no host copy in between."""
         if self._fallback:
             self.host_gathers += 1
-            return ops.Fuse.apply(self, [dt.to_table()], ctx)
+            return ops.Fuse.apply(self, [dt.to_table(self._node)], ctx)
         if self._vmap_fallback:
             self.host_gathers += 1
-            return JittedFuse.apply(self, [dt.to_table()], ctx)
+            return JittedFuse.apply(self, [dt.to_table(self._node)], ctx)
         try:
             out_dt = self._run_device(dt, donate=True)
         except ops.TypecheckError:
@@ -892,18 +904,18 @@ class BatchedJittedFuse(JittedFuse):
             if self._jit_succeeded:
                 self._latch("vmap", e)
                 self.host_gathers += 1
-                return JittedFuse.apply(self, [dt.to_table()], ctx)
+                return JittedFuse.apply(self, [dt.to_table(self._node)], ctx)
             if self._batch_succeeded:
                 raise
             self._latch("fuse", e)
             self.host_gathers += 1
-            return ops.Fuse.apply(self, [dt.to_table()], ctx)
+            return ops.Fuse.apply(self, [dt.to_table(self._node)], ctx)
         self._batch_succeeded = True
         if emit_device:
             out_dt.donatable = donate_out
             return out_dt
         self.host_gathers += 1
-        return out_dt.to_table()
+        return out_dt.to_table(self._node)
 
     def apply_batched(self, tables: List[Table], ctx=None, *,
                       emit_device: bool = False,
@@ -961,7 +973,7 @@ class BatchedJittedFuse(JittedFuse):
                 dt = DeviceTable.from_columns(
                     t.schema, cols, [t.rows[i].row_id for i in idxs],
                     [t.rows[i].group for i in idxs], pad_to=bucket,
-                    grouping=t.grouping)
+                    grouping=t.grouping, node=self._node)
                 self.host_stacks += 1
                 was_fresh = EXECUTABLE_CACHE.misses
                 out_dt = self._run_device(dt, donate=True)
@@ -975,7 +987,7 @@ class BatchedJittedFuse(JittedFuse):
                 # path — while numpy row views are free.  Downstream
                 # consumers (jnp ops, lowered chains) take ndarray
                 # transparently via jnp.asarray.
-                for pos, row in out_dt.host_rows():
+                for pos, row in out_dt.host_rows(self._node):
                     out_rows[idxs[pos]] = row
                 self.host_gathers += 1
                 if len(groups) == 1 and EXECUTABLE_CACHE.misses == was_fresh:

@@ -19,13 +19,14 @@ device->host boundary in ``host_rows``/``to_table``).
 from __future__ import annotations
 
 import itertools
-import threading
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from repro.obs.trace import region
 
 Schema = List[Tuple[str, type]]
 
@@ -132,34 +133,10 @@ def schema_compatible(a: Schema, b: Schema) -> bool:
 #: payload crossing the PCIe boundary.
 HOST_COPIES: Dict[str, int] = {"stacks": 0, "gathers": 0}
 
-# per-thread copy capture: an executor thread brackets one item's
-# execution with start/end and gets THAT item's copy counts, without
-# the races a global-counter delta would have across worker threads
-_copy_capture = threading.local()
-
 
 def note_host_copy(kind: str) -> None:
-    """Count one host<->device bulk copy ('stacks' or 'gathers') against
-    the global counters and, when the current thread has a capture open,
-    against that capture."""
+    """Count one host<->device bulk copy ('stacks' or 'gathers')."""
     HOST_COPIES[kind] += 1
-    cap = getattr(_copy_capture, "counts", None)
-    if cap is not None:
-        cap[kind] = cap.get(kind, 0) + 1
-
-
-def copy_capture_start() -> None:
-    """Begin attributing this thread's host copies (until
-    :func:`copy_capture_end`) to the current work item."""
-    _copy_capture.counts = {}
-
-
-def copy_capture_end() -> Optional[Dict[str, int]]:
-    """Close this thread's capture; returns the counts since start (None
-    when no capture was open, {} when no copies happened)."""
-    cap = getattr(_copy_capture, "counts", None)
-    _copy_capture.counts = None
-    return cap
 
 
 def reset_host_copies() -> None:
@@ -204,20 +181,25 @@ class DeviceTable:
     def from_columns(schema: Schema, host_cols: Sequence[Sequence[Any]],
                      row_ids: Sequence[int], groups: Sequence[Any],
                      pad_to: Optional[int] = None,
-                     grouping: Optional[str] = None) -> "DeviceTable":
+                     grouping: Optional[str] = None,
+                     node: Optional[str] = None) -> "DeviceTable":
         """Build from per-column lists of per-row host (numpy) arrays: one
         ``np.stack`` memcpy + ONE device upload per column.  The row count
         is padded up to ``pad_to`` by repeating row 0 so device shapes stay
         bucket-sized; padding rows carry no mask entry — ``nrows`` bounds
-        the live range."""
+        the live range.  ``node`` names the upload's ``repro.stack``
+        region."""
         n = len(row_ids)
         cap = max(pad_to or n, n)
         columns = []
-        for col in host_cols:
-            col = list(col)
-            stacked = np.stack(col + col[:1] * (cap - n)) if col else \
-                np.zeros((0,))
-            columns.append(jnp.asarray(stacked))
+        with region("stack", node=node) as reg:
+            for col in host_cols:
+                col = list(col)
+                stacked = np.stack(col + col[:1] * (cap - n)) if col else \
+                    np.zeros((0,))
+                columns.append(jnp.asarray(stacked))
+            if reg:
+                reg.set_metadata(bytes=sum(c.nbytes for c in columns))
         note_host_copy("stacks")
         return DeviceTable(schema, columns, n, row_ids, groups,
                            grouping=grouping, mask=None, donatable=True)
@@ -292,14 +274,18 @@ class DeviceTable:
                            grouping=self.grouping, mask=mask, donatable=True)
 
     # -- device->host boundary ----------------------------------------------
-    def host_rows(self) -> List[Tuple[int, Row]]:
+    def host_rows(self, node: Optional[str] = None) -> List[Tuple[int, Row]]:
         """Materialize live rows as ``(position, Row)`` pairs with ONE
-        device->host readback; masked-out (filtered) and padding rows are
-        compacted away here — and only here."""
+        device->host readback (a ``repro.gather`` region named after
+        ``node``); masked-out (filtered) and padding rows are compacted
+        away here — and only here."""
         payload = tuple(self.columns)
         if self.mask is not None:
             payload = payload + (self.mask,)
-        host = jax.device_get(payload)
+        with region("gather", node=node) as reg:
+            if reg:
+                reg.set_metadata(bytes=sum(a.nbytes for a in payload))
+            host = jax.device_get(payload)
         note_host_copy("gathers")
         ncol = len(self.columns)
         mask_h = host[ncol] if self.mask is not None else None
@@ -311,9 +297,9 @@ class DeviceTable:
                                self.row_ids[i], self.groups[i])))
         return out
 
-    def to_table(self) -> Table:
+    def to_table(self, node: Optional[str] = None) -> Table:
         t = Table(self.schema, grouping=self.grouping)
-        t.rows = [r for _, r in self.host_rows()]
+        t.rows = [r for _, r in self.host_rows(node)]
         return t
 
 

@@ -211,6 +211,17 @@ def _stage_fn(fname: str, argnames, inner, ret_arity: int):
     return f
 
 
+def _named_scope(stage: str, fn):
+    """``fn`` with its body under ``jax.named_scope(stage)``: the stage
+    names the device operations it lowers to (op metadata only, so the
+    compiled program and its compile-cache key are unchanged)."""
+    @functools.wraps(fn)
+    def scoped(*args):
+        with jax.named_scope(stage):
+            return fn(*args)
+    return scoped
+
+
 def _rowwise_native_batch(batched, multi: bool):
     """Row-wise view of a natively-batched stage fn ``batched(params,
     *cols)``: untransformed calls run the stage with B=1; under
@@ -354,7 +365,7 @@ def model_stage_op(model: Model, params, stage: str, *,
                          "(logits | prefill | decode)")
 
     multi = stage != "logits"
-    pure = _rowwise_native_batch(batched, multi=multi)
+    pure = _rowwise_native_batch(_named_scope(stage, batched), multi=multi)
     fn = _stage_fn(f"{model_name}_{stage}", argnames,
                    functools.partial(pure, params), len(names))
     fn.__pure__, fn.__consts__ = pure, params

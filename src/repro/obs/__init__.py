@@ -10,7 +10,8 @@ a human — can answer "why did this request miss its deadline?":
 * :mod:`repro.obs.trace` — ``Trace``/``Span``/``Tracer``: monotonic-clock
   spans on a per-request trace carried by ``RequestContext``; head
   sampling plus tail-based always-keep for SLO-miss/error/shed/retried
-  traces; bounded ring buffer of kept traces.
+  traces; bounded ring buffer of kept traces.  ``region``: host work
+  on the ``jax.profiler`` trace as ``repro.<kind>`` annotations.
 * :mod:`repro.obs.metrics` — log-bucketed mergeable ``Histogram`` and
   time-``WindowedCounter``, the bounded replacements for unbounded
   per-key value lists; ``EVENTS``, the process's totals of events no
@@ -35,12 +36,12 @@ from repro.obs.export import (export_chrome, to_chrome_events, to_json,
                               write_chrome)
 from repro.obs.metrics import (EVENTS, EventCounts, Histogram,
                                HistogramSnapshot, WindowedCounter)
-from repro.obs.trace import Span, Trace, Tracer
+from repro.obs.trace import Span, Trace, Tracer, region
 
 __all__ = [
     "Attribution", "NodeBreakdown", "attribute", "keys", "now",
     "export_chrome", "to_chrome_events", "to_json", "write_chrome",
     "EVENTS", "EventCounts", "Histogram", "HistogramSnapshot",
     "WindowedCounter",
-    "Span", "Trace", "Tracer",
+    "Span", "Trace", "Tracer", "region",
 ]

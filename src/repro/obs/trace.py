@@ -26,13 +26,28 @@ Thread-safety: spans are appended from executor callback threads and
 hedge/retry timers; appends are list-atomic under the GIL and the keep
 ring is lock-protected.  All timestamps are ``repro.obs.clock.now``
 (monotonic) — never wall clock.
+
+Regions
+-------
+:func:`region` puts a piece of host work (or a host block: an upload, a
+``device_get``, a dispatch) on the ``jax.profiler`` trace, as a
+``TraceAnnotation`` named ``repro.<kind>`` whose arguments carry the
+node, rows, bytes, ... and ``perf_ns``, the region's start on this
+module's clock in ns.  The median of (profiler start - ``perf_ns``) over
+a trace's regions is the offset between the two clocks; with it every
+retroactive :class:`Span` maps onto the device timeline.  Spans stay the
+record of waits that are not host work (a batcher queue, an executor
+queue).  With the profiler off a region is one activity check.
 """
 from __future__ import annotations
 
 import itertools
 import threading
+import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.clock import now
 
@@ -41,6 +56,38 @@ _trace_ids = itertools.count(1)
 #: event names that flip a trace's tail-keep flags when recorded
 _RETRY_EVENTS = frozenset({"retry", "requeue"})
 _HEDGE_EVENTS = frozenset({"hedge_launch"})
+
+
+class _Off:
+    """The region while no profiler records: enters and exits doing
+    nothing, and is falsy, so a site skips computing late arguments."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def __bool__(self) -> bool:
+        return False
+
+
+_OFF = _Off()
+
+
+def region(kind: str, **args):
+    """Context manager putting host work on the profiler's trace as
+    ``repro.<kind>`` with ``args`` (plus ``perf_ns``) as its arguments.
+    While no profiler records it returns one shared inert object, after
+    one activity check.  Arguments known only inside the region go in
+    with ``set_metadata`` behind an ``if`` on the region (falsy when
+    off)."""
+    if not TraceAnnotation.is_enabled():
+        return _OFF
+    return TraceAnnotation("repro." + kind, perf_ns=time.perf_counter_ns(),
+                           **args)
 
 
 class Span:

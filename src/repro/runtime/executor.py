@@ -30,7 +30,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from repro.core.lowering import DegradePolicy, degraded_execution
-from repro.core.table import copy_capture_end, copy_capture_start
+from repro.obs.trace import region
 from repro.runtime.kvs import KVS, CacheClient
 from repro.runtime.netmodel import NetModel, nbytes
 from repro.serving.admission import DeadlineExceeded
@@ -73,11 +73,11 @@ class WorkItem:
     # observability: every attempt of the logical item (original, crash
     # requeue, hedge, retry clone) appends events to ONE shared log —
     # ("start"|"cancelled"|"requeue", executor_id, t) and
-    # ("done", executor_id, t, queue_s, exec_s, copies) — so the single
+    # ("done", executor_id, t, queue_s, exec_s) — so the single
     # winning callback can reconstruct the full attempt history
     attempt_log: List[Tuple] = dataclasses.field(default_factory=list)
-    # host<->device copy counts captured around THIS item's execution
-    copies: Optional[Dict[str, int]] = None
+    # the DAG node this item runs: names its ``repro.exec`` region
+    node: Optional[str] = None
 
     def clone(self) -> "WorkItem":
         """A redispatchable copy sharing this item's completion token and
@@ -89,7 +89,7 @@ class WorkItem:
                         deadline_t=self.deadline_t, degrade=self.degrade,
                         token=self.token, dispatch_key=self.dispatch_key,
                         attempt=self.attempt,
-                        attempt_log=self.attempt_log)
+                        attempt_log=self.attempt_log, node=self.node)
 
     def deliver(self, result, error, executor_id: Optional[str]) -> bool:
         """Claim the completion and fire the callback; False if another
@@ -242,27 +242,22 @@ class Executor:
                     if src is not None and src != self.id:
                         self.net.charge(nbytes(t))
                 ctx = ExecutionContext(self, item)
-                copy_capture_start()
-                try:
+                with region("exec", node=item.node, executor=self.id):
                     if item.degrade is not None:
                         with degraded_execution(item.degrade):
                             result = item.fn(item.tables, ctx)
                     else:
                         result = item.fn(item.tables, ctx)
-                finally:
-                    item.copies = copy_capture_end()
                 t_end = time.perf_counter()
                 item.exec_s = t_end - t_start
                 item.attempt_log.append(("done", self.id, t_end,
-                                         item.queue_s, item.exec_s,
-                                         item.copies))
+                                         item.queue_s, item.exec_s))
                 item.deliver(result, None, self.id)
             except BaseException as e:
                 t_end = time.perf_counter()
                 item.exec_s = t_end - t_start
                 item.attempt_log.append(("done", self.id, t_end,
-                                         item.queue_s, item.exec_s,
-                                         item.copies))
+                                         item.queue_s, item.exec_s))
                 item.deliver(None, e, self.id)
             finally:
                 self.current = None

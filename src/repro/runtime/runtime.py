@@ -23,7 +23,7 @@ from repro.core.table import DeviceTable, Table
 from repro.obs import keys as okeys
 from repro.obs.clock import now as _mono
 from repro.obs.metrics import Histogram, HistogramSnapshot, WindowedCounter
-from repro.obs.trace import Trace, Tracer
+from repro.obs.trace import Trace, Tracer, region
 from repro.runtime.dag import RuntimeDag, RuntimeNode
 from repro.runtime.executor import ExecutorPool, WorkItem
 from repro.runtime.kvs import KVS
@@ -78,8 +78,6 @@ def _exec_span_cb(tr: Trace, node_name: str, item, cb,
         if done is not None:
             attrs["queue_s"] = done[3]
             attrs["exec_s"] = done[4]
-            if done[5]:
-                attrs["copies"] = done[5]
         if error is not None:
             attrs["error"] = type(error).__name__
         _trace_exec_events(tr, node_name, log)
@@ -390,7 +388,7 @@ class Runtime:
                         produced_on=produced_on, callback=callback,
                         deadline_t=ctx.deadline_t if ctx else None,
                         degrade=ctx.degrade if ctx else None,
-                        dispatch_key=key)
+                        dispatch_key=key, node=node.name)
         tr = ctx.trace if ctx is not None else None
         if tr is not None:
             item.callback = _exec_span_cb(tr, node.name, item, callback,
@@ -714,6 +712,15 @@ class Runtime:
     def _make_batch_fn(self, node: RuntimeNode, dag_name: str = "",
                        dag: Optional[RuntimeDag] = None):
         def batched(arg_list):
+            # runs on the batcher's flush thread
+            with region("flush", node=node.name,
+                        requests=len(arg_list)) as reg:
+                if reg:
+                    reg.set_metadata(rows=sum(
+                        len(t.rows) for e in arg_list for t in e[0]))
+                return flush(arg_list)
+
+        def flush(arg_list):
             # merge all request tables into one invocation (paper §4)
             live = []
             for entry in arg_list:
@@ -784,7 +791,8 @@ class Runtime:
             # across crash requeues / hedges of the whole batch
             item = WorkItem(fn=fn, tables=[big], produced_on=[None],
                             callback=None, deadline_t=batch_deadline,
-                            dispatch_key=(dag_name, node.name, bid))
+                            dispatch_key=(dag_name, node.name, bid),
+                            node=node.name)
 
             # metric series are keyed by (dag, node) so two DAGs sharing a
             # node name don't interleave their histograms (generations of
@@ -793,6 +801,10 @@ class Runtime:
             mkey = okeys.batch_prefix(dag_name, node.name)
 
             def demux(result, error, exec_id):
+                with region("demux", node=node.name, rows=len(big.rows)):
+                    demux_rows(result, error, exec_id)
+
+            def demux_rows(result, error, exec_id):
                 t_done = _mono()
                 lat = t_done - t_submit
                 self.record_metric(okeys.batch(mkey, "size"), len(big.rows))
@@ -812,8 +824,6 @@ class Runtime:
                     if done_e is not None:
                         base["queue_s"] = done_e[3]
                         base["exec_s"] = done_e[4]
-                        if done_e[5]:
-                            base["copies"] = done_e[5]
                     if error is not None:
                         base["error"] = type(error).__name__
                     for trc in traced:
@@ -947,6 +957,12 @@ class Runtime:
     def call_dag(self, name: str, table: Table, *,
                  deadline_s: Optional[float] = None,
                  klass: Optional[str] = None) -> Future:
+        with region("call", dag=name):
+            return self._call_dag(name, table, deadline_s, klass)
+
+    def _call_dag(self, name: str, table: Table,
+                  deadline_s: Optional[float],
+                  klass: Optional[str]) -> Future:
         # ONE registry read per request: the whole execution runs on the
         # generation that was live at arrival, even if a blue/green swap
         # lands mid-flight

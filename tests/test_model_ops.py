@@ -6,6 +6,8 @@ cost hooks that seed the estimator's curves, and drive the SLO
 controller's propose -> hot-apply tick.  Also covers the
 distribution-aware warm walk (observed buckets first).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -105,6 +107,35 @@ def test_prefill_decode_stages_match_model_loop(stages):
 
     assert got == want
     assert got_row == want[0]
+
+
+def _stage_of(op_name):
+    """The serving stage an op's ``op_name`` metadata puts it under:
+    a path segment ``prefill`` / ``decode`` / ``logits``, bare or
+    wrapped by a transform (``vmap(decode)``)."""
+    for seg in op_name.split("/"):
+        m = re.fullmatch(r"(?:\w+\()*(prefill|decode|logits)\)*", seg)
+        if m:
+            return m.group(1)
+    return None
+
+
+def test_stages_name_their_device_ops(stages):
+    """Each stage's body runs under ``jax.named_scope(stage)``, so the
+    compiled program's op metadata (what the profiler's op events carry)
+    names the stage, per row and under vmap."""
+    cfg, _, _, lg, pre, dec = stages
+    toks = _toks(cfg, 2)
+
+    def chain(t):
+        return dec.fn(*pre.fn(t))
+
+    for fn, arg, want in ((chain, toks[0], {"prefill", "decode"}),
+                          (jax.vmap(chain), toks, {"prefill", "decode"}),
+                          (lg.fn, toks[0], {"logits"})):
+        hlo = jax.jit(fn).lower(arg).compile().as_text()
+        got = {_stage_of(n) for n in re.findall(r'op_name="([^"]*)"', hlo)}
+        assert want <= got, got
 
 
 def test_weights_reach_executables_as_arguments(stages, rt):

@@ -16,9 +16,16 @@
 * fault-aware estimator: measured fault pressure inflates the predicted
   p99 (zero rates leave it exactly unchanged);
 * clock audit: every rate window and trace timestamp reads the ONE
-  monotonic clock in ``repro.obs.clock``.
+  monotonic clock in ``repro.obs.clock``;
+* profiler regions: a lowered flow served under ``jax.profiler`` leaves
+  one ``repro.<kind>`` annotation per host boundary, with its arguments,
+  a dispatch inside its executor item, and a clock stamp that maps the
+  retroactive spans onto the profiler's timeline; with the profiler off
+  a region is one shared inert object.
 """
+import collections
 import json
+import statistics
 import threading
 import time
 
@@ -642,3 +649,182 @@ def test_rate_windows_share_the_monotonic_clock():
     s = t.event("retry@n")
     t1 = time.perf_counter()
     assert t0 <= s.t0 <= t1
+
+
+# ---------------------------------------------------------------------------
+# profiler regions
+# ---------------------------------------------------------------------------
+
+REGION_ARGS = {
+    "repro.call": {"dag"},
+    "repro.flush": {"node", "requests", "rows"},
+    "repro.exec": {"node", "executor"},
+    "repro.dispatch": {"node", "path", "rows"},
+    "repro.stack": {"node", "bytes"},
+    "repro.gather": {"node", "bytes"},
+    "repro.demux": {"node", "rows"},
+}
+
+
+def _region_flow():
+    import jax
+    import jax.numpy as jnp
+
+    def scale(x: jax.Array) -> jax.Array:
+        return jnp.tanh(x * 1.5)
+
+    def shift(x: jax.Array) -> jax.Array:
+        return x - 0.25
+
+    fl = Dataflow([("x", jax.Array)])
+    fl.output = fl.map(scale, names=["x"], gpu=True, batching=True) \
+        .map(shift, names=["x"], gpu=True, batching=True)
+    return fl
+
+
+def _host_regions(xplane):
+    """``[(line index, name, start_ns, end_ns, args)]`` of the
+    ``repro.*`` events on the host planes of a profiler trace."""
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(xplane).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("repro."):
+                    out.append((i, e.name, e.start_ns, e.end_ns,
+                                dict(e.stats)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def region_trace(tmp_path_factory):
+    """One singleton and one merged burst of four through a lowered
+    chain, under the profiler: the regions and the kept traces."""
+    import glob
+
+    import jax
+    import numpy as np
+
+    from repro.core.compiler import compile_flow
+    from repro.core.lowering import BatchedJittedFuse, forced_batched_routing
+
+    rt = Runtime(n_cpu=1, n_gpu=1, net=NetModel(scale=0.0),
+                 tracer=Tracer(enabled=True, sample_rate=1.0),
+                 batch_wait_ms=50.0)
+    try:
+        dep = compile_flow(_region_flow(), rt, fusion=True, name="rg")
+        (chain,) = [o.op for o in dep.plan.ops]
+        assert isinstance(chain, BatchedJittedFuse)
+
+        def table(i):
+            return Table([("x", jax.Array)],
+                         [(np.linspace(-1.0, 1.0, 16) * (i + 1),)])
+
+        def serve(n):
+            futs = [rt.call_dag("rg", table(i)) for i in range(n)]
+            return [f.result(timeout=60) for f in futs]
+
+        (node,) = dep.dag.nodes
+        with forced_batched_routing([chain]):
+            serve(1)
+            serve(4)                          # compiles bucket 4
+            batcher = rt.batcher_for("rg", node)
+            batcher.adaptive_wait = False     # the burst merges whole
+            rt.tracer.clear()
+            log_dir = str(tmp_path_factory.mktemp("regions"))
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(log_dir, profiler_options=opts)
+            try:
+                serve(1)
+                serve(4)
+            finally:
+                jax.profiler.stop_trace()
+        (xplane,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+        yield {"regions": _host_regions(xplane), "node": node,
+               "chain": chain.name, "traces": rt.tracer.kept("rg")}
+    finally:
+        rt.stop()
+
+
+def test_each_region_appears_with_its_arguments(region_trace):
+    regs = region_trace["regions"]
+    names = {r[1] for r in regs}
+    assert names == set(REGION_ARGS), names
+    for _, name, t0, t1, args in regs:
+        assert REGION_ARGS[name] | {"perf_ns"} <= set(args), (name, args)
+        assert t1 >= t0
+    by = collections.defaultdict(list)
+    for r in regs:
+        by[r[1]].append(r[4])
+    assert [a["dag"] for a in by["repro.call"]] == ["rg"] * 5
+    node, chain = region_trace["node"], region_trace["chain"]
+    for kind in ("repro.flush", "repro.exec", "repro.demux"):
+        assert {a["node"] for a in by[kind]} == {node}, kind
+    for kind in ("repro.dispatch", "repro.stack", "repro.gather"):
+        assert {a["node"] for a in by[kind]} == {chain}, kind
+    assert sorted((a["requests"], a["rows"])
+                  for a in by["repro.flush"]) == [(1, 1), (4, 4)]
+    assert sorted((a["path"], a["rows"], a.get("bucket"))
+                  for a in by["repro.dispatch"]) == [("batch", 4, 4),
+                                                     ("row", 1, None)]
+    # the burst's four rows of 16 float32 go up once and come back once
+    assert [a["bytes"] for a in by["repro.stack"]] == [4 * 16 * 4]
+    assert [a["bytes"] for a in by["repro.gather"]] == [4 * 16 * 4]
+    assert sorted(a["rows"] for a in by["repro.demux"]) == [1, 4]
+
+
+def test_dispatch_nests_inside_exec(region_trace):
+    regs = region_trace["regions"]
+    execs = [r for r in regs if r[1] == "repro.exec"]
+    for line, _, t0, t1, _ in (r for r in regs
+                               if r[1] in ("repro.dispatch", "repro.stack",
+                                           "repro.gather")):
+        assert any(e[0] == line and e[2] <= t0 and t1 <= e[3]
+                   for e in execs)
+
+
+def test_clock_offset_maps_exec_spans_onto_exec_regions(region_trace):
+    """The median of (profiler start - perf_ns) over every region maps
+    each ``exec@`` span onto the profiler's clock: the span (executor
+    queue + service) holds its ``repro.exec`` region, and the end of its
+    service, start + ``queue_s`` + ``exec_s``, lies within 100 us of the
+    region's end."""
+    regs = region_trace["regions"]
+    offset = statistics.median(t0 - a["perf_ns"] for _, _, t0, _, a in regs)
+    execs = [(t0, t1) for _, name, t0, t1, _ in regs if name == "repro.exec"]
+    spans = [s for t in region_trace["traces"] for s in t.spans
+             if s.kind == "exec"]
+    assert len(spans) == 5 and len(execs) == 2
+    for s in spans:
+        t0, t1 = s.t0 * 1e9 + offset, s.t1 * 1e9 + offset
+        done = t0 + (s.attrs["queue_s"] + s.attrs["exec_s"]) * 1e9
+        a, b = min(execs, key=lambda r: abs(r[1] - done))
+        assert abs(b - done) < 100e3
+        assert t0 <= a and b <= t1
+
+
+def test_regions_record_nothing_with_the_profiler_off(tmp_path):
+    """Off, every region is one shared falsy object; a trace taken after
+    traffic served with the profiler off holds no region."""
+    import glob
+
+    import jax
+
+    from repro.obs import region
+    from repro.obs.trace import _OFF
+    assert region("call", dag="d") is region("exec") is _OFF
+    assert not region("gather")
+    rt = _traced_runtime()
+    try:
+        fl = _flow(batching=True)
+        fl.deploy(rt, name="off")
+        fl.execute(_t(1)).result(timeout=10)
+        jax.profiler.start_trace(str(tmp_path))
+        jax.profiler.stop_trace()
+    finally:
+        rt.stop()
+    (xplane,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    assert _host_regions(xplane) == []
