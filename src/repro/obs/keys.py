@@ -49,7 +49,7 @@ def dag(dag_name: str, series: str) -> str:
 
 # -- per-node batcher stream ------------------------------------------------
 
-BATCH_SERIES = ("size", "latency_s", "exec_s", "expired_t")
+BATCH_SERIES = ("size", "latency_s", "exec_s", "expired_t", "gate_wait_s")
 
 
 def batch_prefix(dag_name: str, node: str) -> str:

@@ -11,12 +11,15 @@ Scheduling policy (paper §2.3/§4):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import random
 import threading
 import time
 from concurrent.futures import Future
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import jax
 
 from repro.core.lowering import DEFAULT_BUCKETS, DegradePolicy, bucket_rows
 from repro.core.table import DeviceTable, Table
@@ -30,7 +33,7 @@ from repro.runtime.kvs import KVS
 from repro.runtime.netmodel import NetModel
 from repro.serving.admission import (AdmissionController, DeadlineExceeded,
                                      Overloaded)
-from repro.serving.batcher import Batcher
+from repro.serving.batcher import Batcher, DeviceGate
 from repro.serving.faults import FaultInjector, FaultPlan
 from repro.serving.retry import CompletionToken, ExecutorLost, RetryPolicy
 
@@ -84,6 +87,38 @@ def _exec_span_cb(tr: Trace, node_name: str, item, cb,
         tr.span(f"exec@{node_name}", t_enq, t1, link=link, **attrs)
         cb(result, error, exec_id)
     return wrapped
+
+
+def _device_ready(result) -> Optional[Callable[[], bool]]:
+    """What a device gate runs on the flush thread once ``result``'s
+    batch completed: block until its device arrays are computed, True if
+    they were not yet.  The one-row path answers with device arrays; the
+    batched path has already gathered to the host (None: nothing to
+    wait for)."""
+    if isinstance(result, DeviceTable):
+        if result.donatable:
+            # its consumer may donate (delete) these buffers at any time,
+            # and a deleted array cannot even be asked whether it is ready
+            return None
+        arrays = list(result.columns) + (
+            [result.mask] if result.mask is not None else [])
+    elif isinstance(result, Table):
+        arrays = [v for r in result.rows for v in r.values
+                  if isinstance(v, jax.Array)]
+    else:
+        return None
+    if not arrays:
+        return None
+
+    def ready() -> bool:
+        try:
+            if all(a.is_ready() for a in arrays):
+                return False
+            jax.block_until_ready(arrays)
+        except RuntimeError:
+            pass        # failed on the device: it holds the device no more
+        return True
+    return ready
 
 
 @dataclasses.dataclass
@@ -665,7 +700,16 @@ class Runtime:
         waiter threads).  Batchers are keyed ``(dag, generation, node)``:
         two DAGs sharing a node name — or two generations of one DAG mid
         blue/green swap — never share a batcher, whose batch fn captured
-        exactly one generation's node closure."""
+        exactly one generation's node closure.
+
+        A node that lowered to a jitted chain on accelerator executors
+        gets a :class:`DeviceGate` on its batcher: at most one batch in
+        flight per executor that may serve the node, the next collected
+        only once the last one's device arrays are ready, so requests
+        that arrive meanwhile merge in the batcher instead of queueing
+        one by one in the device's program stream.  CPU nodes run their
+        batches synchronously and keep fanning them out over every
+        executor of their class."""
         dag_name = dag.name if dag is not None else ""
         generation = dag.generation if dag is not None else 0
         key = (dag_name, generation, node.name)
@@ -701,6 +745,9 @@ class Runtime:
                             max_wait_ms=float(cfg.get("batch_wait_ms",
                                                       self.batch_wait_ms)),
                             on_drop=_drop)
+                if node.jitted and node.resource_class == "gpu":
+                    b.gate = DeviceGate(node.name, functools.partial(
+                        self._slots, node))
                 self._batchers[key] = b
         try:
             b.submit((tables, produced_on, callback, locality_key, ctx,
@@ -708,6 +755,11 @@ class Runtime:
                      deadline_t=ctx.deadline_t if ctx else None)
         except RuntimeError as e:       # closed under our feet (stop())
             callback(None, e, None)
+
+    def _slots(self, node: RuntimeNode) -> int:
+        """Executors that may serve ``node``: the batches its device gate
+        lets run at once."""
+        return len(self.pool.candidates(node.name, node.resource_class))
 
     def _make_batch_fn(self, node: RuntimeNode, dag_name: str = "",
                        dag: Optional[RuntimeDag] = None):
@@ -771,6 +823,7 @@ class Runtime:
                 generation=dag.generation if dag is not None else 0)
             reordered = bool(batcher is not None
                              and batcher.last_reordered)
+            gate = batcher.gate if batcher is not None else None
             traced = [c.trace for _, _, _, _, c, _ in live
                       if c is not None and c.trace is not None]
             for _, _, _, _, c, tq in live:
@@ -799,10 +852,18 @@ class Runtime:
             # one DAG intentionally share a series — the controller reads
             # one continuous signal across a blue/green swap)
             mkey = okeys.batch_prefix(dag_name, node.name)
+            if batcher is not None and batcher.last_gate_wait_s is not None:
+                self.record_metric(okeys.batch(mkey, "gate_wait_s"),
+                                   batcher.last_gate_wait_s)
 
             def demux(result, error, exec_id):
-                with region("demux", node=node.name, rows=len(big.rows)):
-                    demux_rows(result, error, exec_id)
+                try:
+                    with region("demux", node=node.name,
+                                rows=len(big.rows)):
+                        demux_rows(result, error, exec_id)
+                finally:
+                    if gate is not None:
+                        gate.release(token, _device_ready(result))
 
             def demux_rows(result, error, exec_id):
                 t_done = _mono()
@@ -926,8 +987,14 @@ class Runtime:
             # merged batch share the node's class and similar deadlines)
             ctx0 = next((c for _, _, _, _, c, _ in live if c is not None),
                         None)
-            self._submit_resilient(node, ex, item, ctx0,
-                                   dag_name=dag_name, traces=traced)
+            token = gate.hold(batch_deadline) if gate is not None else None
+            try:
+                self._submit_resilient(node, ex, item, ctx0,
+                                       dag_name=dag_name, traces=traced)
+            except BaseException:
+                if gate is not None:
+                    gate.release(token)
+                raise
             return [None] * len(arg_list)
 
         return batched

@@ -12,9 +12,16 @@ before dispatch — they fail fast with a typed
 :class:`~repro.serving.admission.DeadlineExceeded` instead of occupying
 batch slots, and ``on_drop`` + the ``expired`` counter surface every such
 decision to the runtime's metrics.
+
+Device gate: a batcher in front of a program that runs on an accelerator
+may be given a :class:`DeviceGate`.  Its flush thread then collects the
+next batch only once the device has finished the last one, so requests
+that arrive meanwhile wait in this queue, where they merge, and not one
+by one in the device's program stream.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import queue
 import time
@@ -22,6 +29,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.obs.trace import region
 from repro.serving.admission import DeadlineExceeded
 
 
@@ -49,6 +57,89 @@ class BatchItem:
         self.done = False
 
 
+class DeviceGate:
+    """Holds a batcher's next batch back while the device runs its last.
+
+    The batch fn takes a slot with :meth:`hold` as it hands a batch to
+    the device; the batch's completion gives the slot back with
+    :meth:`release` on every path (result, error, expiry, exhausted
+    retries), passing ``ready``: a callable that blocks until the
+    batch's device arrays are computed and says whether it had to.  The
+    flush thread calls :meth:`wait` before it collects a batch: it blocks
+    while ``slots()`` batches are held, then runs the released batches'
+    ``ready`` itself, so the executor thread that released them never
+    waits on the device.  A hold lapses at its ``until`` (the batch's
+    deadline, else ``LIMIT_S`` after the hold): a lost completion must
+    not wedge the node."""
+
+    #: how long a hold without a deadline may keep the next batch back
+    LIMIT_S = 30.0
+
+    def __init__(self, node: str, slots: Callable[[], int]):
+        self.node = node            # names the ``repro.gate`` region
+        self.slots = slots          # batches the device may run at once
+        self._cv = threading.Condition()
+        self._held: Dict[int, float] = {}     # token -> when it lapses
+        self._ready: List[Callable[[], bool]] = []
+        self._tokens = itertools.count()
+        self._open = False
+
+    def hold(self, until: Optional[float] = None) -> int:
+        """Take a slot for a batch about to reach the device; returns the
+        token its completion passes to :meth:`release`."""
+        with self._cv:
+            tok = next(self._tokens)
+            if not self._open:
+                self._held[tok] = (until if until is not None
+                                   else time.perf_counter() + self.LIMIT_S)
+            return tok
+
+    def release(self, token: int,
+                ready: Optional[Callable[[], bool]] = None) -> None:
+        """Give back ``token``'s slot; a no-op once it lapsed or was
+        released."""
+        with self._cv:
+            if self._held.pop(token, None) is None:
+                return
+            if ready is not None:
+                self._ready.append(ready)
+            self._cv.notify_all()
+
+    def open(self) -> None:
+        """Hold nothing from now on (the batcher is closing)."""
+        with self._cv:
+            self._open = True
+            self._held.clear()
+            self._ready.clear()
+            self._cv.notify_all()
+
+    def in_flight(self) -> int:
+        """Batches not yet known to have finished on the device."""
+        with self._cv:
+            return len(self._held) + len(self._ready)
+
+    def wait(self) -> Tuple[bool, bool]:
+        """Block until a slot is free and every batch released since the
+        last wait has finished on the device.  Returns ``(in_flight,
+        lapsed)``: whether a batch was still running, and whether a hold
+        lapsed."""
+        in_flight = lapsed = False
+        with self._cv:
+            while len(self._held) >= max(1, self.slots()):
+                in_flight = True
+                tok = min(self._held, key=self._held.get)
+                left = self._held[tok] - time.perf_counter()
+                if left <= 0:
+                    del self._held[tok]
+                    lapsed = True
+                else:
+                    self._cv.wait(left)
+            ready, self._ready = self._ready, []
+        for r in ready:
+            in_flight = r() or in_flight
+        return in_flight, lapsed
+
+
 class Batcher:
     """Micro-batching queue in front of a batched function.
 
@@ -56,13 +147,21 @@ class Batcher:
     responsible for stacking/padding).  ``max_batch`` bounds the bucket
     (paper default: 10); ``max_wait_ms`` bounds queueing delay.
 
-    The wait deadline is *adaptive*: an EWMA of recent inter-arrival gaps
+    A batch takes every item already queued when it is collected, up to
+    ``max_batch``; the wait window only governs arrivals not yet queued.
+    The window is *adaptive*: an EWMA of recent inter-arrival gaps
     decides how much of ``max_wait`` is actually worth spending.  Under
     dense traffic (gaps well inside the window) the full window is used and
     requests coalesce; under sparse traffic the wait shrinks toward zero —
     a lone request should not sit out the whole window when the expected
     next arrival lies beyond it.  ``adaptive_wait=False`` restores the
     fixed-deadline behavior.
+
+    ``gate``, when the runtime sets one (a :class:`DeviceGate`), holds
+    each batch back until the device has finished the batch before it;
+    ``gate_waits`` counts the batches that found it still running and
+    ``gate_wait_s`` the seconds they waited, ``gate_lapses`` the holds
+    that ran out before their completion released them.
     """
 
     #: EWMA smoothing for inter-arrival gaps.
@@ -104,6 +203,13 @@ class Batcher:
         #: written by the flush thread just before it invokes ``fn``, read
         #: by the batch fn (same thread) to annotate the batch-level span
         self.last_reordered = False
+        self.gate: Optional[DeviceGate] = None
+        self.gate_waits = 0
+        self.gate_wait_s = 0.0
+        self.gate_lapses = 0
+        #: seconds the batch being flushed waited at the gate (None when
+        #: the device had finished): read by the batch fn, as above
+        self.last_gate_wait_s: Optional[float] = None
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
         self.batch_sizes: List[int] = []
@@ -174,10 +280,11 @@ class Batcher:
             return self._gap_ewma
 
     def effective_wait(self) -> float:
-        """How long the batch loop holds a partial batch open.  Arrivals
-        expected WITHIN the window keep the full window (so every merge
-        the fixed deadline achieved still happens); beyond it the wait
-        shrinks linearly, reaching zero at twice the window — a lone
+        """How long the batch loop holds a partial batch open for
+        arrivals not yet queued (what is queued is taken at once).
+        Arrivals expected WITHIN the window keep the full window (so every
+        merge the fixed deadline achieved still happens); beyond it the
+        wait shrinks linearly, reaching zero at twice the window — a lone
         request during sparse traffic fires immediately."""
         if not self.adaptive_wait:
             return self.max_wait
@@ -217,23 +324,33 @@ class Batcher:
             except BaseException:
                 pass
 
+    def _pass_gate(self) -> None:
+        """Wait at the device gate, if there is one, and count the wait
+        when the device was still running the last batch."""
+        self.last_gate_wait_s = None
+        gate = self.gate
+        if gate is None:
+            return
+        t0 = time.perf_counter()
+        with region("gate", node=gate.node) as reg:
+            if reg:
+                reg.set_metadata(in_flight=gate.in_flight())
+            in_flight, lapsed = gate.wait()
+        self.gate_lapses += lapsed
+        if in_flight:
+            waited = time.perf_counter() - t0
+            self.gate_waits += 1
+            self.gate_wait_s += waited
+            self.last_gate_wait_s = waited
+
     def _collect(self) -> List[BatchItem]:
-        """One flush worth of items: queue arrivals (holding the adaptive
-        window open only when there is no deferred backlog) merged with
-        the backlog, expired items failed, the rest EDF-ordered."""
+        """One flush worth of items: the first arrival (or the deferred
+        backlog); then, past the device gate, every item already queued;
+        then arrivals within the adaptive window, held open only when
+        there is no backlog.  Merged with the backlog, expired items
+        failed, the rest EDF-ordered."""
         items: List[BatchItem] = []
-        if self._backlog:
-            # deferred items already waited out a window — drain whatever
-            # the queue has RIGHT NOW and flush without holding another
-            while len(items) + len(self._backlog) < self.max_batch:
-                try:
-                    nxt = self.q.get_nowait()
-                except queue.Empty:
-                    break
-                if nxt is _WAKE:
-                    break
-                items.append(nxt)
-        else:
+        if not self._backlog:
             try:
                 first = self.q.get(timeout=0.1)
             except queue.Empty:
@@ -241,6 +358,20 @@ class Batcher:
             if first is _WAKE:
                 return []                   # close() signal; re-check _stop
             items = [first]
+        self._pass_gate()
+        woke = False
+        while len(items) + len(self._backlog) < self.max_batch:
+            try:
+                nxt = self.q.get_nowait()
+            except queue.Empty:
+                break
+            if nxt is _WAKE:
+                woke = True                 # flush what we hold, then exit
+                break
+            items.append(nxt)
+        if not self._backlog and not woke:
+            # no window with a backlog: deferred items already waited
+            # one out
             deadline = time.perf_counter() + self.effective_wait()
             while len(items) < self.max_batch:
                 remaining = deadline - time.perf_counter()
@@ -251,7 +382,7 @@ class Batcher:
                 except queue.Empty:
                     break
                 if nxt is _WAKE:
-                    break                   # flush what we hold, then exit
+                    break
                 items.append(nxt)
         pool = self._backlog + items        # backlog first: it is older
         self._backlog = []
@@ -311,6 +442,8 @@ class Batcher:
             if self._stop:
                 return
             self._stop = True
+        if self.gate is not None:
+            self.gate.open()
         # wake the loop out of its poll so the join below returns
         # promptly — close() may run on an executor callback thread (the
         # generation-drain path), where a poll-timeout-long block would

@@ -471,3 +471,237 @@ def test_batcher_drain_on_reregistration(rt):
         .result(timeout=10).rows[0].values[0] == 20
     # the old deployment's batcher was retired (and closed once drained)
     assert rt._batchers                     # fresh batcher exists
+
+
+# ---------------------------------------------------------------------------
+# Device gate: one batch in flight, what waits meanwhile merges
+# ---------------------------------------------------------------------------
+
+def _until(cond, timeout=2.0):
+    deadline = time.perf_counter() + timeout
+    while not cond():
+        if time.perf_counter() > deadline:
+            return False
+        time.sleep(0.005)
+    return True
+
+
+def _gated_batcher(max_batch=4):
+    """A Batcher whose fn holds the gate for every batch it flushes; the
+    test releases the holds by hand."""
+    from repro.serving.batcher import DeviceGate
+
+    gate = DeviceGate("node", lambda: 1)
+    batches, tokens = [], []
+
+    def fn(args):
+        batches.append(list(args))
+        tokens.append(gate.hold(time.perf_counter() + 30.0))
+        return list(args)
+
+    b = Batcher(fn, max_batch=max_batch, max_wait_ms=1.0)
+    b.gate = gate
+    return b, gate, batches, tokens
+
+
+def test_gate_merges_items_queued_while_held_in_arrival_order():
+    b, gate, batches, tokens = _gated_batcher(max_batch=4)
+    try:
+        assert b.submit(0).event.wait(2.0)
+        items = [b.submit(i) for i in range(1, 7)]
+        time.sleep(0.1)                    # the flush thread waits
+        assert batches == [[0]]
+        assert b.gate_waits == 0
+        gate.release(tokens[0])
+        assert items[3].event.wait(2.0)
+        assert batches[1] == [1, 2, 3, 4]  # at most max_batch
+        gate.release(tokens[1])
+        assert items[5].event.wait(2.0)
+        assert batches == [[0], [1, 2, 3, 4], [5, 6]]
+        assert b.gate_waits == 2 and b.gate_wait_s >= 0.1
+        assert b.gate_lapses == 0
+    finally:
+        b.close()
+
+
+def test_gate_released_on_close():
+    b, gate, batches, tokens = _gated_batcher()
+    assert b.submit(0).event.wait(2.0)
+    held = [b.submit(i) for i in range(1, 4)]
+    time.sleep(0.05)
+    t0 = time.perf_counter()
+    b.close()
+    assert time.perf_counter() - t0 < 1.0
+    for it in held:                    # flushed or failed, none left hanging
+        assert it.event.wait(1.0)
+    assert _until(lambda: not b._thread.is_alive())
+    assert gate.in_flight() == 0
+
+
+def test_gate_hold_lapses_at_its_deadline():
+    """A hold whose completion never comes lapses at the batch deadline,
+    and the lapse is counted."""
+    from repro.serving.batcher import DeviceGate
+
+    gate = DeviceGate("node", lambda: 1)
+    b = Batcher(lambda args: list(args), max_batch=4, max_wait_ms=1.0)
+    b.gate = gate
+    try:
+        gate.hold(time.perf_counter() + 0.1)
+        t0 = time.perf_counter()
+        assert b.call(1, timeout=5.0) == 1
+        assert 0.05 <= time.perf_counter() - t0 < 2.0
+        assert b.gate_lapses == 1 and b.gate_waits == 1
+    finally:
+        b.close()
+
+
+def test_collect_takes_queued_items_when_the_window_is_zero():
+    started, go = threading.Event(), threading.Event()
+    batches = []
+
+    def fn(args):
+        batches.append(list(args))
+        started.set()
+        go.wait(2.0)
+        return list(args)
+
+    b = Batcher(fn, max_batch=8, max_wait_ms=0.0)
+    try:
+        b.submit(0)
+        assert started.wait(2.0)
+        items = [b.submit(i) for i in range(1, 6)]
+        assert b.effective_wait() == 0.0
+        go.set()
+        assert all(it.event.wait(2.0) for it in items)
+        assert batches == [[0], [1, 2, 3, 4, 5]]
+    finally:
+        b.close()
+
+
+def _slow_chain_runtime(jax, jnp, iters=1_000_000):
+    """A lowered one-map chain on one gpu executor whose every dispatch
+    runs for tens of milliseconds; no batch window, so only the gate can
+    merge."""
+    from repro.core.compiler import compile_flow
+    from repro.core.passes import LowerJaxChainsPass, PassPipeline
+
+    def slow(x: jax.Array) -> jax.Array:
+        return jax.lax.fori_loop(
+            0, iters, lambda i, v: jnp.tanh(v * 1.01 + 0.1), x)
+
+    rt2 = Runtime(n_cpu=1, n_gpu=1, net=NetModel(scale=0.0),
+                  batch_wait_ms=0.0)
+    fl = Dataflow([("x", jax.Array)])
+    fl.output = fl.map(slow, names=["x"], gpu=True, batching=True)
+    dep = compile_flow(fl, rt2, name="g", pipeline=PassPipeline(
+        [LowerJaxChainsPass(min_ops=1)]))
+    (chain,) = [o.op for o in dep.plan.ops]
+    (node,) = dep.dag.nodes
+    return rt2, slow, chain, node
+
+
+def _xs(i):
+    return np.linspace(-1.0, 1.0, 16).astype(np.float32) * (i + 1)
+
+
+def test_gate_merges_requests_sent_while_a_dispatch_runs():
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    from repro.core.lowering import BatchedJittedFuse, forced_batched_routing
+
+    rt2, slow, chain, node = _slow_chain_runtime(jax, jnp)
+    try:
+        assert isinstance(chain, BatchedJittedFuse)
+
+        def send(i):
+            return rt2.call_dag("g", Table([("x", jax.Array)], [(_xs(i),)]))
+
+        with forced_batched_routing([chain]):
+            send(0).result(timeout=60)     # compiles the per-row program
+            chain.warm([Table([("x", jax.Array)],
+                              [(_xs(i),) for i in range(4)])])
+            b = rt2.batcher_for("g", node)
+            assert b.gate is not None
+            n0, d0 = len(b.batch_sizes), chain.batch_dispatches
+            futs = [send(0)]
+            assert _until(lambda: len(b.batch_sizes) == n0 + 1)
+            futs += [send(i) for i in range(1, 5)]
+            outs = [f.result(timeout=60) for f in futs]
+        assert b.batch_sizes[n0:] == [1, 4]
+        assert chain.batch_dispatches - d0 >= 1
+        assert b.gate_waits >= 1
+        waits = rt2.metrics_snapshot("batch/g/")
+        assert any(k.endswith("/gate_wait_s") and v
+                   for k, v in waits.items())
+        ref = jax.jit(slow)
+        for i, out in enumerate(outs):
+            np.testing.assert_allclose(np.asarray(out.rows[0].values[0]),
+                                       np.asarray(ref(_xs(i))), rtol=1e-5)
+    finally:
+        rt2.stop()
+
+
+@pytest.mark.parametrize("ending", ["error", "deadline"])
+def test_gate_released_when_the_batch_does_not_complete(ending):
+    """A batch that fails, or expires in the executor's queue, gives its
+    slot back: the next request is not held until the hold lapses."""
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    from repro.runtime.executor import WorkItem
+
+    rt2, _, chain, node = _slow_chain_runtime(jax, jnp, iters=10)
+    try:
+        def table(v):
+            return Table([("x", jax.Array)], [(v,)])
+
+        rt2.call_dag("g", table(_xs(0))).result(timeout=60)
+        b = rt2.batcher_for("g", node)
+        if ending == "error":
+            # not an array: the proven executable refuses it at dispatch
+            fut = rt2.call_dag("g", table("not an array"))
+        else:
+            (ex,) = rt2.pool.by_class("gpu")
+            ex.submit(WorkItem(fn=lambda tables, ctx: time.sleep(0.3),
+                               tables=[], produced_on=[],
+                               callback=lambda *a: None))
+            fut = rt2.call_dag("g", table(_xs(1)), deadline_s=0.05)
+        with pytest.raises(Exception):
+            fut.result(timeout=10)
+        assert _until(lambda: b.gate.in_flight() == 0)
+        t0 = time.perf_counter()
+        rt2.call_dag("g", table(_xs(2))).result(timeout=10)
+        assert time.perf_counter() - t0 < 5.0
+        assert b.gate_lapses == 0
+    finally:
+        rt2.stop()
+
+
+def test_cpu_batched_node_still_runs_batches_concurrently():
+    """CPU nodes get no gate: two executors run two batches at once."""
+    lock = threading.Lock()
+    running = {"now": 0, "peak": 0}
+
+    def fn(x: int) -> int:
+        with lock:
+            running["now"] += 1
+            running["peak"] = max(running["peak"], running["now"])
+        time.sleep(0.2)
+        with lock:
+            running["now"] -= 1
+        return x * 10
+
+    rt2 = Runtime(n_cpu=2, net=NetModel(scale=0.0), max_batch=2,
+                  batch_wait_ms=5.0)
+    try:
+        fl = _batched_flow(rt2, fn)
+        futs = [fl.execute(Table([("x", int)], [(i,)])) for i in range(4)]
+        outs = [f.result(timeout=10).rows[0].values[0] for f in futs]
+        assert outs == [i * 10 for i in range(4)]
+        assert running["peak"] == 2
+        (b,) = rt2._batchers.values()
+        assert b.gate is None
+    finally:
+        rt2.stop()

@@ -663,6 +663,7 @@ REGION_ARGS = {
     "repro.stack": {"node", "bytes"},
     "repro.gather": {"node", "bytes"},
     "repro.demux": {"node", "rows"},
+    "repro.gate": {"node", "in_flight"},
 }
 
 
@@ -761,8 +762,11 @@ def test_each_region_appears_with_its_arguments(region_trace):
         by[r[1]].append(r[4])
     assert [a["dag"] for a in by["repro.call"]] == ["rg"] * 5
     node, chain = region_trace["node"], region_trace["chain"]
-    for kind in ("repro.flush", "repro.exec", "repro.demux"):
+    for kind in ("repro.flush", "repro.exec", "repro.demux", "repro.gate"):
         assert {a["node"] for a in by[kind]} == {node}, kind
+    # the burst's flush finds the singleton's device result not yet
+    # waited for; the singleton's finds the gathered burst before it
+    assert sorted(a["in_flight"] for a in by["repro.gate"]) == [0, 1]
     for kind in ("repro.dispatch", "repro.stack", "repro.gather"):
         assert {a["node"] for a in by[kind]} == {chain}, kind
     assert sorted((a["requests"], a["rows"])
