@@ -84,7 +84,8 @@ class ModelConfig:
     accum_dtype: str = "float32"      # bf16 for 480B-class (memory, DESIGN §4)
 
     # --- kernels ---
-    use_pallas: bool = False          # Pallas kernels (interpret on CPU)
+    use_pallas: bool = False          # Pallas kernels (interpret on CPU);
+                                      # no effect on family "ssm" (rwkv6)
     kv_quant: bool = False            # int8 KV cache (beyond-paper, §Perf C)
     expert_quant: bool = False        # int8 expert weights (serving, §Perf A)
     bf16_boundary: bool = False       # pin bf16 at reshard boundaries (§Perf B)

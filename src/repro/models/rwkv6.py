@@ -2,10 +2,12 @@
 [arXiv:2404.05892]
 
 State per layer: wkv matrix state S [B, H, hd, hd] + token-shift states.
-Training uses a ``lax.scan`` over time (the Pallas chunked kernel in
-``repro.kernels.wkv6`` is the TPU fast path, validated against this).
-Heads are sharded over the ``model`` axis; the recurrence is elementwise in
-the sharded dims so the scan body has no collectives.
+The WKV recurrence takes one of two forms, chosen from the number of
+timesteps: a prompt (prefill, logits, training) runs ``wkv_chunked``, which
+reads and writes the state once per chunk of ``CHUNK`` timesteps and forms
+the pairs inside a chunk all at once; a decode step (one timestep) runs
+``wkv_scan``.  Heads are sharded over the ``model`` axis; the recurrence is
+elementwise in the sharded dims, so neither form has collectives.
 """
 from __future__ import annotations
 
@@ -17,10 +19,13 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import layers
 from repro.models.partition import AxisInfo, shard, dp_axes, mp_axis
+from repro.obs import keys as okeys
+from repro.obs.metrics import EVENTS
 
 TM_LORA = 32
 DECAY_LORA = 64
 MIX = ("w", "k", "v", "r", "g")
+CHUNK = 16      # timesteps between two reads of the WKV state on a prompt
 
 
 def init_params(key, cfg: ModelConfig, ax: Optional[AxisInfo], **_unused):
@@ -104,7 +109,61 @@ def wkv_scan(r, k, v, w, u, state):
     return jnp.moveaxis(ys, 0, 1), state
 
 
-def _time_mix(x, xprev, S, lp, cfg: ModelConfig, ax, *, need_state=True):
+def wkv_chunked(r, k, v, logw, u, state, *, chunk: int = CHUNK):
+    """The recurrence of ``wkv_scan`` over chunks of ``chunk`` timesteps.
+
+    r,k,v: [B, T, H, hd]; logw: [B, T, H, hd] log of the decay (<= 0, so
+    a decay that underflows to 0 stays exact); u: [H, hd]; state:
+    [B, H, hd, hd].  Returns (y [B,T,H,hd], new_state).
+
+    Inside a chunk that enters with state S_0, with c_t the sum of logw up
+    to and including t and b_t the sum before t:
+    y_t = (r_t * e^b_t) S_0 + sum_{s<t} A_ts v_s + (r_t . u*k_t) v_t, with
+    A_ts = sum_i r_ti k_si e^(b_ti - c_si), and the chunk leaves the state
+    e^c_last * S_0 + sum_s (k_s * e^(c_last - c_s)) v_s.  Every exponent
+    is a sum of logw over a run of timesteps, so none is positive; pairs
+    s >= t are masked before the exp.  A tail that fills no whole chunk is
+    padded with k = v = r = 0 and logw = 0, which leaves y and the state
+    as they were.  Only the state crosses chunks: a ``lax.scan`` of T/chunk
+    steps carries it.
+    """
+    B, T, H, hd = r.shape
+    C = min(chunk, T)
+    N = -(-T // C)
+    hi = jax.lax.Precision.HIGHEST
+
+    def chunks(x):                          # -> [N, B, H, C, hd]
+        x = jnp.pad(x, ((0, 0), (0, N * C - T), (0, 0), (0, 0)))
+        return x.reshape(B, N, C, H, hd).transpose(1, 0, 3, 2, 4)
+
+    def before(x):                          # x_{t-1} along the chunk, 0 first
+        return jnp.pad(x[..., :-1, :], ((0, 0),) * 3 + ((1, 0), (0, 0)))
+
+    r, k, v, logw = map(chunks, (r, k, v, logw))
+    c = jnp.cumsum(logw, axis=3)            # through t
+    b = before(c)                           # before t
+    after = jnp.flip(before(jnp.cumsum(jnp.flip(logw, 3), 3)), 3)  # after t
+    tri = jnp.tril(jnp.ones((C, C), bool), -1)[:, :, None]         # s < t
+    a = jnp.sum(r[..., :, None, :] * k[..., None, :, :] * jnp.exp(
+        jnp.where(tri, b[..., :, None, :] - c[..., None, :, :], -jnp.inf)),
+        axis=-1)
+    bonus = jnp.sum(r * u[:, None, :] * k, axis=-1, keepdims=True)
+    y_in = jnp.einsum("nbhts,nbhsj->nbhtj", a, v, precision=hi) + bonus * v
+
+    def step(S, inp):
+        r_in, k_out, v_n, decay = inp
+        y = jnp.einsum("bhti,bhij->bhtj", r_in, S, precision=hi)
+        S = decay[..., None] * S + jnp.einsum(
+            "bhsi,bhsj->bhij", k_out, v_n, precision=hi)
+        return S, y
+
+    S, y_state = jax.lax.scan(step, state, (
+        r * jnp.exp(b), k * jnp.exp(after), v, jnp.exp(c[..., -1, :])))
+    y = (y_state + y_in).transpose(1, 0, 3, 2, 4).reshape(B, N * C, H, hd)
+    return y[:, :T], S
+
+
+def _time_mix(x, xprev, S, lp, cfg: ModelConfig, ax):
     B, T, D = x.shape
     H, hd = cfg.num_rwkv_heads, cfg.rwkv_head_dim
     xw, xk, xv, xr, xg = _ddlerp(x, xprev, lp)
@@ -118,25 +177,16 @@ def _time_mix(x, xprev, S, lp, cfg: ModelConfig, ax, *, need_state=True):
     k = shard(ax, k, dm, None, mp_axis(ax))
     v = shard(ax, v, dm, None, mp_axis(ax))
     decay_low = jnp.tanh(xw @ lp["dw1"]) @ lp["dw2"]
-    w = jnp.exp(-jnp.exp((lp["w0"] + decay_low).astype(f32)))  # [B,T,D]
-    w = shard(ax, w, dm, None, mp_axis(ax))
-    hshape = (B, T, H, hd)
-    if cfg.use_pallas and T > 1:
-        from repro.kernels import ops as kops
-        # kernel covers the zero-state fresh-sequence path (train/prefill);
-        # single-token decode (nonzero state) uses the scan below
-        y = kops.wkv6(r.reshape(hshape), k.reshape(hshape),
-                      v.reshape(hshape), w.reshape(hshape),
-                      lp["u"].astype(f32),
-                      chunk=min(64, T))
-        if need_state:  # prefill: tail state for the decode cache
-            _, S = wkv_scan(r.reshape(hshape), k.reshape(hshape),
-                            v.reshape(hshape), w.reshape(hshape),
-                            lp["u"].astype(f32), S)
+    logw = -jnp.exp((lp["w0"] + decay_low).astype(f32))  # [B,T,D], log w
+    logw = shard(ax, logw, dm, None, mp_axis(ax))
+    r, k, v, logw = (t.reshape(B, T, H, hd) for t in (r, k, v, logw))
+    u = lp["u"].astype(f32)
+    if T > 1:
+        EVENTS.inc(okeys.WKV_CHUNKED)
+        y, S = wkv_chunked(r, k, v, logw, u, S)
     else:
-        y, S = wkv_scan(r.reshape(hshape), k.reshape(hshape),
-                        v.reshape(hshape), w.reshape(hshape),
-                        lp["u"].astype(f32), S)
+        EVENTS.inc(okeys.WKV_STEP)
+        y, S = wkv_scan(r, k, v, jnp.exp(logw), u, S)
     y = layers.groupnorm_heads(y.reshape(B, T, D), lp["gn_scale"],
                                lp["gn_bias"], H)
     out = ((y * g).astype(x.dtype)) @ lp["wo"]
@@ -169,8 +219,7 @@ def forward(params, tokens, cfg: ModelConfig, ax: Optional[AxisInfo], *,
         zeros_shift = jnp.zeros((B, x.shape[-1]), x.dtype)
         S0 = jnp.zeros((B, H, hd, hd), jnp.float32)
         h1 = layers.layernorm(x, lp["ln1"]["scale"], lp["ln1"]["bias"])
-        tm_out, S = _time_mix(h1, _shift(h1, zeros_shift), S0, lp, cfg,
-                              ax, need_state=build_cache)
+        tm_out, S = _time_mix(h1, _shift(h1, zeros_shift), S0, lp, cfg, ax)
         x = x + tm_out
         h2 = layers.layernorm(x, lp["ln2"]["scale"], lp["ln2"]["bias"])
         x = (x + _channel_mix(h2, _shift(h2, zeros_shift), lp)).astype(

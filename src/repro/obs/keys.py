@@ -23,6 +23,9 @@ themselves contain ``/``):
   (:data:`REPLAN_ROLLBACK`)
 * ``lowering/latch/{kind}`` — a lowered chain gave up an executable
   (:data:`LOWERING_LATCHES`; counted in :data:`repro.obs.EVENTS`)
+* ``model/wkv/chunked``, ``model/wkv/step`` — a trace of the RWKV-6 WKV
+  recurrence, by the form it took (:data:`WKV_CHUNKED`, :data:`WKV_STEP`;
+  counted in :data:`repro.obs.EVENTS`)
 
 ``*_t`` series are event timestamps (windowed counters); the rest are
 value histograms (``Runtime.record_metric`` routes on the suffix).
@@ -118,6 +121,14 @@ def lowering_latch(kind: str) -> str:
     return f"lowering/latch/{kind}"
 
 
+# -- model paths ------------------------------------------------------------
+
+#: the form a trace of the RWKV-6 WKV recurrence took: chunked — a
+#: prompt, the state read once per chunk; step — one timestep a step
+WKV_CHUNKED = "model/wkv/chunked"
+WKV_STEP = "model/wkv/step"
+
+
 # -- the registry lint ------------------------------------------------------
 
 _PATTERNS: List[re.Pattern] = [
@@ -129,6 +140,8 @@ _PATTERNS: List[re.Pattern] = [
     re.compile(re.escape(FAULT_REQUEUED) + r"\Z"),
     re.compile(re.escape(REPLAN_ROLLBACK) + r"\Z"),
     re.compile(r"\Alowering/latch/(" + "|".join(LOWERING_LATCHES) + r")\Z"),
+    re.compile(re.escape(WKV_CHUNKED) + r"\Z"),
+    re.compile(re.escape(WKV_STEP) + r"\Z"),
 ]
 
 

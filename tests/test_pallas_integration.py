@@ -28,7 +28,7 @@ def _compare(arch, tol=0.05):
     assert rel < tol, (arch, rel)
 
 
-@pytest.mark.parametrize("arch", ["yi-9b", "gemma2-9b", "rwkv6-1.6b"])
+@pytest.mark.parametrize("arch", ["yi-9b", "gemma2-9b"])
 def test_pallas_forward_matches_jnp(arch):
     _compare(arch)
 

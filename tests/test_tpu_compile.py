@@ -15,15 +15,18 @@ entry written for a described chip cannot be read back without one).
 import dataclasses
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.rwkv6_1b6 import CONFIG as RWKV6_1B6
 from repro.configs.yi_9b import CONFIG as YI_9B
 from repro.core.lowering import chain_consts, compose_steps
 from repro.kernels import ops as kops
-from repro.models import build_model
+from repro.models import build_model, rwkv6
 from repro.models.registry import model_stage_op
+from repro.obs import EVENTS, keys as okeys
 
 HBM_BYTES = 16 * 10**9          # one v5e chip
 
@@ -139,4 +142,45 @@ def test_yi_9b_served_chain_fits_one_v5e(one_chip, stages):
     compiled = jax.jit(chain).lower(
         consts, _spec(one_chip, (4, 512), jnp.int32)).compile()
     used = _bytes(compiled)
+    assert used < HBM_BYTES, f"{used / 1e9:.2f} GB > 16 GB"
+
+
+def _scan_lengths(jaxpr) -> set:
+    """The lengths of every ``lax.scan`` in ``jaxpr`` and its sub-jaxprs."""
+    found = set()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.add(eqn.params["length"])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    found |= _scan_lengths(sub)
+    return found
+
+
+def test_rwkv6_served_chain_fits_one_v5e(one_chip):
+    """The chain the rwkv6-1.6b cell serves, at published widths: prefill
+    of 256 tokens, then 16 decode steps, for 8 rows (its largest bucket).
+    The prompt's WKV takes the chunked form, with no loop over its 256
+    timesteps, and the program fits one chip."""
+    model = build_model(RWKV6_1B6)
+    params = _on(one_chip, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    chunked = okeys.WKV_CHUNKED
+    traced = EVENTS.snapshot(chunked).get(chunked, 0)
+    ops = [model_stage_op(model, params, stage, seq_len=256, cache_len=272,
+                          measure=False)
+           for stage in ("prefill",) + ("decode",) * 16]
+    steps = [o.fn for o in ops]
+    consts = chain_consts(steps)
+    assert len(consts) == 1
+    chain = jax.vmap(compose_steps(steps, masked_input=False,
+                                   with_keep=False), in_axes=(None, 0))
+    lowered = jax.jit(chain).trace(
+        consts, _spec(one_chip, (8, 256), jnp.int32))
+    assert EVENTS.snapshot(chunked)[chunked] > traced
+    lengths = _scan_lengths(lowered.jaxpr.jaxpr)
+    assert 256 // rwkv6.CHUNK in lengths and 256 not in lengths, lengths
+    used = _bytes(lowered.lower().compile())
     assert used < HBM_BYTES, f"{used / 1e9:.2f} GB > 16 GB"
